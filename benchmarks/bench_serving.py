@@ -140,6 +140,7 @@ from typing import Optional
 from benchmarks.common import emit
 from repro.core import (AnalyticCostModel, SimConfig, Workload, simulate,
                         throughput_curve)
+from repro.launch.compile_cache import enable_compile_cache
 
 # Turbo runtime ~2.4x faster than PyTorch on short variable-length
 # requests (paper §6.2.1/§6.3: 99 -> 237 resp/s no-batch critical points)
@@ -1208,6 +1209,7 @@ def run(smoke: bool = False, prefix_mix: float = 0.75,
 
 
 if __name__ == "__main__":
+    enable_compile_cache()
     argv = sys.argv[1:]
     usage = ("usage: bench_serving [--smoke] [--prefix-mix FRACTION] "
              "[--sample-candidates N]")

@@ -288,8 +288,8 @@ class TurboClient:
         prefill variant before returning (``client.warmup_stats``);
         ``warmup="background"`` warms on a daemon thread instead.
         ``replicas=N`` returns a `repro.cluster.ReplicaPool` of N such
-        stacks (weights initialised once and placed per replica —
-        sharded over ``jax.devices()`` when more than one is available)
+        stacks (weights initialised once; replica ``i`` keeps its
+        weights, KV pool and decode state on ``jax.devices()[i % n]``)
         behind the same submit/stream/cancel surface."""
         import jax
         from repro.configs import get_config, get_smoke_config

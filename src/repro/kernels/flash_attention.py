@@ -21,20 +21,17 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-# renamed TPUCompilerParams -> CompilerParams across jax releases
-CompilerParams = getattr(pltpu, "CompilerParams", None) or \
-    pltpu.TPUCompilerParams
-
 NEG_INF = -1e30
 
 
-def _flash_kernel(q_ref, k_ref, v_ref, len_ref, o_ref,
+def _flash_kernel(len_ref, q_ref, k_ref, v_ref, o_ref,
                   m_scr, l_scr, acc_scr, *,
                   scale: float, causal: bool, sq: int, sk: int,
                   block_q: int, block_k: int):
     i = pl.program_id(2)        # q block
     j = pl.program_id(3)        # kv block
     nk = pl.num_programs(3)
+    kv_len = jnp.minimum(len_ref[pl.program_id(0)], sk)
 
     @pl.when(j == 0)
     def _init():
@@ -53,7 +50,7 @@ def _flash_kernel(q_ref, k_ref, v_ref, len_ref, o_ref,
             preferred_element_type=jnp.float32) * scale  # (bq, bk)
         qpos = q_start + jax.lax.broadcasted_iota(jnp.int32, s.shape, 0)
         kpos = k_start + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
-        mask = kpos < jnp.minimum(len_ref[0, 0], sk)
+        mask = kpos < kv_len
         if causal:
             mask &= kpos <= qpos
         s = jnp.where(mask, s, NEG_INF)
@@ -108,34 +105,38 @@ def flash_attention_pallas(q: jax.Array, k: jax.Array, v: jax.Array,
     nk = pl.cdiv(sk, bk)
     if lengths is None:
         lengths = jnp.full((b,), sk, jnp.int32)
-    len2d = lengths.astype(jnp.int32).reshape(b, 1)
 
-    grid = (b, h, nq, nk)
     kernel = functools.partial(
         _flash_kernel, scale=scale, causal=causal, sq=sq, sk=sk,
         block_q=bq, block_k=bk)
-    return pl.pallas_call(
-        kernel,
-        grid=grid,
+    # lengths ride in SMEM as a scalar-prefetch operand: a (1, 1) VMEM
+    # block of a (B, 1) array breaks the TPU's (8, 128) tiling rule
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=1,
+        grid=(b, h, nq, nk),
         in_specs=[
-            pl.BlockSpec((1, 1, bq, dh), lambda b_, h_, i, j: (b_, h_, i, 0)),
+            pl.BlockSpec((1, 1, bq, dh),
+                         lambda b_, h_, i, j, n: (b_, h_, i, 0)),
             pl.BlockSpec((1, 1, bk, dh),
-                         lambda b_, h_, i, j, g=g: (b_, h_ // g, j, 0)),
+                         lambda b_, h_, i, j, n, g=g: (b_, h_ // g, j, 0)),
             pl.BlockSpec((1, 1, bk, dh),
-                         lambda b_, h_, i, j, g=g: (b_, h_ // g, j, 0)),
-            pl.BlockSpec((1, 1), lambda b_, h_, i, j: (b_, 0)),
+                         lambda b_, h_, i, j, n, g=g: (b_, h_ // g, j, 0)),
         ],
         out_specs=pl.BlockSpec((1, 1, bq, dh),
-                               lambda b_, h_, i, j: (b_, h_, i, 0)),
-        out_shape=jax.ShapeDtypeStruct((b, h, sq, dh), q.dtype),
+                               lambda b_, h_, i, j, n: (b_, h_, i, 0)),
         scratch_shapes=[
             pltpu.VMEM((bq, 128), jnp.float32),
             pltpu.VMEM((bq, 128), jnp.float32),
             pltpu.VMEM((bq, dh), jnp.float32),
         ],
-        compiler_params=CompilerParams(
+    )
+    return pl.pallas_call(
+        kernel,
+        grid_spec=grid_spec,
+        out_shape=jax.ShapeDtypeStruct((b, h, sq, dh), q.dtype),
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "parallel",
                                  "arbitrary")),
         interpret=interpret,
         name="turbo_flash_attention",
-    )(q, k, v, len2d)
+    )(lengths.astype(jnp.int32), q, k, v)

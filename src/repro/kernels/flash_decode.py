@@ -22,19 +22,16 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-# renamed TPUCompilerParams -> CompilerParams across jax releases
-CompilerParams = getattr(pltpu, "CompilerParams", None) or \
-    pltpu.TPUCompilerParams
-
 NEG_INF = -1e30
 
 
-def _decode_kernel(q_ref, k_ref, v_ref, len_ref, o_ref, m_ref, l_ref,
+def _decode_kernel(len_ref, q_ref, k_ref, v_ref, o_ref, m_ref, l_ref,
                    acc_scr, m_scr, l_scr, *,
                    scale: float, sk: int, block_k: int, split: int):
     j = pl.program_id(3)          # kv block within this split
     nk = pl.num_programs(3)
     s = pl.program_id(2)          # split index
+    kv_len = jnp.minimum(len_ref[pl.program_id(0)], sk)
 
     @pl.when(j == 0)
     def _init():
@@ -49,7 +46,7 @@ def _decode_kernel(q_ref, k_ref, v_ref, len_ref, o_ref, m_ref, l_ref,
         q, k, (((1,), (1,)), ((), ())),
         preferred_element_type=jnp.float32) * scale      # (1, bk)
     kpos = k_start + jax.lax.broadcasted_iota(jnp.int32, st.shape, 1)
-    mask = kpos < jnp.minimum(len_ref[0, 0], sk)
+    mask = kpos < kv_len
     st = jnp.where(mask, st, NEG_INF)
 
     m_prev = m_scr[...]                                  # (1, 128)
@@ -62,8 +59,10 @@ def _decode_kernel(q_ref, k_ref, v_ref, len_ref, o_ref, m_ref, l_ref,
     v = v_ref[0, 0].astype(jnp.float32)                  # (bk, dh)
     # zero masked rows of V: padded blocks read unspecified data (NaN in
     # interpret mode) and 0 * NaN = NaN would poison the accumulator —
-    # must be a select, not a multiply
-    v = jnp.where(mask[0][:, None], v, 0.0)
+    # must be a select, not a multiply.  The row mask is rebuilt along
+    # sublanes: Mosaic cannot transpose the (1, bk) lane mask.
+    vpos = k_start + jax.lax.broadcasted_iota(jnp.int32, v.shape, 0)
+    v = jnp.where(vpos < kv_len, v, 0.0)
     pv = jax.lax.dot_general(
         p, v, (((1,), (0,)), ((), ())),
         preferred_element_type=jnp.float32)              # (1, dh)
@@ -73,9 +72,9 @@ def _decode_kernel(q_ref, k_ref, v_ref, len_ref, o_ref, m_ref, l_ref,
 
     @pl.when(j == nk - 1)
     def _finish():
-        o_ref[0, 0, 0] = acc_scr[...][0].astype(o_ref.dtype)
-        m_ref[0, 0, 0] = m_scr[...][:1, :].astype(m_ref.dtype)[0]
-        l_ref[0, 0, 0] = l_scr[...][:1, :].astype(l_ref.dtype)[0]
+        o_ref[0, 0, 0] = acc_scr[...].astype(o_ref.dtype)
+        m_ref[0, 0, 0] = m_scr[...].astype(m_ref.dtype)
+        l_ref[0, 0, 0] = l_scr[...].astype(l_ref.dtype)
 
 
 def flash_decode_pallas(q: jax.Array, k: jax.Array, v: jax.Array,
@@ -94,55 +93,62 @@ def flash_decode_pallas(q: jax.Array, k: jax.Array, v: jax.Array,
     nk = pl.cdiv(per_split, bk)
     if lengths is None:
         lengths = jnp.full((b,), sk, jnp.int32)
-    len2d = lengths.astype(jnp.int32).reshape(b, 1)
 
-    grid = (b, h, num_splits, nk)
     kernel = functools.partial(
         _decode_kernel, scale=scale, sk=sk, block_k=bk, split=num_splits)
-    out, m, l = pl.pallas_call(
-        kernel,
-        grid=grid,
+    # lengths ride in SMEM as a scalar-prefetch operand (a (1, 1) VMEM
+    # block breaks the TPU's (8, 128) tiling rule)
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=1,
+        grid=(b, h, num_splits, nk),
         in_specs=[
             pl.BlockSpec((1, 1, 1, dh),
-                         lambda b_, h_, s, j: (b_, h_, 0, 0)),
+                         lambda b_, h_, s, j, n: (b_, h_, 0, 0)),
             pl.BlockSpec((1, 1, bk, dh),
-                         lambda b_, h_, s, j, g=g, nk=nk:
+                         lambda b_, h_, s, j, n, g=g, nk=nk:
                          (b_, h_ // g, s * nk + j, 0)),
             pl.BlockSpec((1, 1, bk, dh),
-                         lambda b_, h_, s, j, g=g, nk=nk:
+                         lambda b_, h_, s, j, n, g=g, nk=nk:
                          (b_, h_ // g, s * nk + j, 0)),
-            pl.BlockSpec((1, 1), lambda b_, h_, s, j: (b_, 0)),
         ],
         out_specs=[
-            pl.BlockSpec((1, 1, 1, dh),
-                         lambda b_, h_, s, j: (b_, h_, s, 0)),
-            pl.BlockSpec((1, 1, 1, 128),
-                         lambda b_, h_, s, j: (b_, h_, s, 0)),
-            pl.BlockSpec((1, 1, 1, 128),
-                         lambda b_, h_, s, j: (b_, h_, s, 0)),
-        ],
-        out_shape=[
-            jax.ShapeDtypeStruct((b, h, num_splits, dh), jnp.float32),
-            jax.ShapeDtypeStruct((b, h, num_splits, 128), jnp.float32),
-            jax.ShapeDtypeStruct((b, h, num_splits, 128), jnp.float32),
+            pl.BlockSpec((1, 1, 1, 1, dh),
+                         lambda b_, h_, s, j, n: (b_, h_, s, 0, 0)),
+            pl.BlockSpec((1, 1, 1, 1, 128),
+                         lambda b_, h_, s, j, n: (b_, h_, s, 0, 0)),
+            pl.BlockSpec((1, 1, 1, 1, 128),
+                         lambda b_, h_, s, j, n: (b_, h_, s, 0, 0)),
         ],
         scratch_shapes=[
             pltpu.VMEM((1, dh), jnp.float32),
             pltpu.VMEM((1, 128), jnp.float32),
             pltpu.VMEM((1, 128), jnp.float32),
         ],
-        compiler_params=CompilerParams(
+    )
+    out, m, l = pl.pallas_call(
+        kernel,
+        grid_spec=grid_spec,
+        out_shape=[
+            jax.ShapeDtypeStruct((b, h, num_splits, 1, dh), jnp.float32),
+            jax.ShapeDtypeStruct((b, h, num_splits, 1, 128), jnp.float32),
+            jax.ShapeDtypeStruct((b, h, num_splits, 1, 128), jnp.float32),
+        ],
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "parallel",
                                  "arbitrary")),
         interpret=interpret,
         name="turbo_flash_decode",
-    )(q[:, :, None, :], k, v, len2d)
+    )(lengths.astype(jnp.int32), q[:, :, None, :], k, v)
 
     return _combine_splits(out, m, l, q.dtype)
 
 
 def _combine_splits(out, m, l, dtype):
-    """Merge split partials with a log-sum-exp reduction (cheap, jnp)."""
+    """Merge split partials with a log-sum-exp reduction (cheap, jnp).
+
+    The partials carry a unit axis before the lanes so each kernel block
+    spans the array's last two dims (the TPU tiling rule)."""
+    out, m, l = out[:, :, :, 0], m[:, :, :, 0], l[:, :, :, 0]
     m1 = m[..., 0]                                       # (B,H,S_) lanes dup
     m_star = jnp.max(m1, axis=-1, keepdims=True)         # (B,H,1)
     w = jnp.exp(m1 - m_star)                             # (B,H,S_)
@@ -151,7 +157,7 @@ def _combine_splits(out, m, l, dtype):
     return (num / jnp.maximum(den, 1e-30)[..., None]).astype(dtype)
 
 
-def _paged_decode_kernel(tables_ref, q_ref, k_ref, v_ref, len_ref,
+def _paged_decode_kernel(tables_ref, len_ref, q_ref, k_ref, v_ref,
                          o_ref, m_ref, l_ref, acc_scr, m_scr, l_scr, *,
                          scale: float, block_size: int):
     """Split-K decode over a *block table*: the kv range of split ``s`` is
@@ -161,6 +167,7 @@ def _paged_decode_kernel(tables_ref, q_ref, k_ref, v_ref, len_ref,
     j = pl.program_id(3)          # logical block within this split
     nk = pl.num_programs(3)
     s = pl.program_id(2)          # split index
+    kv_len = len_ref[pl.program_id(0)]
 
     @pl.when(j == 0)
     def _init():
@@ -177,7 +184,7 @@ def _paged_decode_kernel(tables_ref, q_ref, k_ref, v_ref, len_ref,
     kpos = k_start + jax.lax.broadcasted_iota(jnp.int32, st.shape, 1)
     # length alone bounds validity: positions past a row's length sit in
     # trash/unassigned blocks whose table entry is 0
-    mask = kpos < len_ref[0, 0]
+    mask = kpos < kv_len
     st = jnp.where(mask, st, NEG_INF)
 
     m_prev = m_scr[...]                                  # (1, 128)
@@ -189,7 +196,9 @@ def _paged_decode_kernel(tables_ref, q_ref, k_ref, v_ref, len_ref,
     l_new = l_scr[...] * alpha + jnp.sum(p, axis=-1, keepdims=True)
     v = v_ref[0, 0].astype(jnp.float32)                  # (bs, dh)
     # select, not multiply: unwritten block contents are unspecified
-    v = jnp.where(mask[0][:, None], v, 0.0)
+    # (the row mask is rebuilt along sublanes, as in _decode_kernel)
+    vpos = k_start + jax.lax.broadcasted_iota(jnp.int32, v.shape, 0)
+    v = jnp.where(vpos < kv_len, v, 0.0)
     pv = jax.lax.dot_general(
         p, v, (((1,), (0,)), ((), ())),
         preferred_element_type=jnp.float32)              # (1, dh)
@@ -199,9 +208,9 @@ def _paged_decode_kernel(tables_ref, q_ref, k_ref, v_ref, len_ref,
 
     @pl.when(j == nk - 1)
     def _finish():
-        o_ref[0, 0, 0] = acc_scr[...][0].astype(o_ref.dtype)
-        m_ref[0, 0, 0] = m_scr[...][:1, :].astype(m_ref.dtype)[0]
-        l_ref[0, 0, 0] = l_scr[...][:1, :].astype(l_ref.dtype)[0]
+        o_ref[0, 0, 0] = acc_scr[...].astype(o_ref.dtype)
+        m_ref[0, 0, 0] = m_scr[...].astype(m_ref.dtype)
+        l_ref[0, 0, 0] = l_scr[...].astype(l_ref.dtype)
 
 
 def flash_decode_paged_pallas(q: jax.Array, k_pool: jax.Array,
@@ -235,7 +244,6 @@ def flash_decode_paged_pallas(q: jax.Array, k_pool: jax.Array,
     tables = jnp.pad(block_tables.astype(jnp.int32), ((0, 0), (0, pad)))
     if lengths is None:
         lengths = jnp.full((b,), mb * bs, jnp.int32)
-    len2d = lengths.astype(jnp.int32).reshape(b, 1)
     # (KV, NB, BS, dh): the (bs, dh) tile pallas DMAs per step is then the
     # trailing-2-dim tile TPU tiling wants
     kt = jnp.transpose(k_pool, (2, 0, 1, 3))
@@ -244,27 +252,28 @@ def flash_decode_paged_pallas(q: jax.Array, k_pool: jax.Array,
     grid = (b, h, num_splits, nk)
     kernel = functools.partial(
         _paged_decode_kernel, scale=scale, block_size=bs)
+    # the lengths are a second scalar-prefetch operand (SMEM): a (1, 1)
+    # VMEM block breaks the TPU's (8, 128) tiling rule
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=1,
+        num_scalar_prefetch=2,
         grid=grid,
         in_specs=[
             pl.BlockSpec((1, 1, 1, dh),
-                         lambda b_, h_, s, j, t: (b_, h_, 0, 0)),
+                         lambda b_, h_, s, j, t, n: (b_, h_, 0, 0)),
             pl.BlockSpec((1, 1, bs, dh),
-                         lambda b_, h_, s, j, t, g=g, nk=nk:
+                         lambda b_, h_, s, j, t, n, g=g, nk=nk:
                          (h_ // g, t[b_, s * nk + j], 0, 0)),
             pl.BlockSpec((1, 1, bs, dh),
-                         lambda b_, h_, s, j, t, g=g, nk=nk:
+                         lambda b_, h_, s, j, t, n, g=g, nk=nk:
                          (h_ // g, t[b_, s * nk + j], 0, 0)),
-            pl.BlockSpec((1, 1), lambda b_, h_, s, j, t: (b_, 0)),
         ],
         out_specs=[
-            pl.BlockSpec((1, 1, 1, dh),
-                         lambda b_, h_, s, j, t: (b_, h_, s, 0)),
-            pl.BlockSpec((1, 1, 1, 128),
-                         lambda b_, h_, s, j, t: (b_, h_, s, 0)),
-            pl.BlockSpec((1, 1, 1, 128),
-                         lambda b_, h_, s, j, t: (b_, h_, s, 0)),
+            pl.BlockSpec((1, 1, 1, 1, dh),
+                         lambda b_, h_, s, j, t, n: (b_, h_, s, 0, 0)),
+            pl.BlockSpec((1, 1, 1, 1, 128),
+                         lambda b_, h_, s, j, t, n: (b_, h_, s, 0, 0)),
+            pl.BlockSpec((1, 1, 1, 1, 128),
+                         lambda b_, h_, s, j, t, n: (b_, h_, s, 0, 0)),
         ],
         scratch_shapes=[
             pltpu.VMEM((1, dh), jnp.float32),
@@ -276,14 +285,14 @@ def flash_decode_paged_pallas(q: jax.Array, k_pool: jax.Array,
         kernel,
         grid_spec=grid_spec,
         out_shape=[
-            jax.ShapeDtypeStruct((b, h, num_splits, dh), jnp.float32),
-            jax.ShapeDtypeStruct((b, h, num_splits, 128), jnp.float32),
-            jax.ShapeDtypeStruct((b, h, num_splits, 128), jnp.float32),
+            jax.ShapeDtypeStruct((b, h, num_splits, 1, dh), jnp.float32),
+            jax.ShapeDtypeStruct((b, h, num_splits, 1, 128), jnp.float32),
+            jax.ShapeDtypeStruct((b, h, num_splits, 1, 128), jnp.float32),
         ],
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "parallel",
                                  "arbitrary")),
         interpret=interpret,
         name="turbo_flash_decode_paged",
-    )(tables, q[:, :, None, :], kt, vt, len2d)
+    )(tables, lengths.astype(jnp.int32), q[:, :, None, :], kt, vt)
     return _combine_splits(out, m, l, q.dtype)

@@ -7,11 +7,11 @@ kernel keeps the whole row in VMEM and
 
   1. takes the greedy ``argmax`` (the short-circuit for temperature<=0
      rows — mixed batches stop paying the sampled path for them),
-  2. temperature-scales and max-peels the top ``cands`` candidates into
-     a VMEM scratch (``cands`` iterations of max+argmax, no sort; tie
-     order matches ``lax.top_k`` — lowest index first),
+  2. temperature-scales and max-peels the top ``cands`` candidates
+     (``cands`` iterations of max+argmax, no sort; tie order matches
+     ``lax.top_k`` — lowest index first),
   3. applies the top-k mask, the nucleus (top-p) mask over the
-     exclusive-cumsum of the candidate softmax, and picks via the
+     exclusive prefix sum of the candidate softmax, and picks via the
      Gumbel-max trick: ``argmax(vals + gumbel)`` over the kept set is an
      exact categorical draw from the renormalized kept distribution.
 
@@ -32,31 +32,44 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
-from jax.experimental.pallas import tpu as pltpu
 
 
-def _sample_kernel(x_ref, t_ref, k_ref, p_ref, g_ref, o_ref,
-                   vals_ref, idx_ref, *, cols: int, cands: int):
+def _argmax_first(x, iota):
+    """Row max and the LOWEST index that holds it, both (br, 1): the tie
+    order of ``jnp.argmax`` and ``lax.top_k``, spelled with a max and a
+    min reduction so that it does not depend on how the TPU lowers
+    argmax."""
+    m = jnp.max(x, axis=-1, keepdims=True)
+    big = jnp.iinfo(jnp.int32).max
+    return m, jnp.min(jnp.where(x == m, iota, big), axis=-1, keepdims=True)
+
+
+def _sample_kernel(x_ref, t_ref, k_ref, p_ref, g_ref, o_ref, *,
+                   cols: int, cands: int):
     x = x_ref[...].astype(jnp.float32)                   # (br, Cp)
     col = jax.lax.broadcasted_iota(jnp.int32, x.shape, 1)
     x = jnp.where(col < cols, x, -jnp.inf)               # mask padded cols
-    greedy = jnp.argmax(x, axis=-1).astype(jnp.int32)    # (br,)
+    _, greedy = _argmax_first(x, col)                    # (br, 1)
 
     temp = jnp.maximum(t_ref[...], 1e-6)                 # (br, 1)
     work = x / temp
+    br = x.shape[0]
+    cand = jax.lax.broadcasted_iota(jnp.int32, (br, cands), 1)
 
-    def peel(j, w):
-        m = jnp.max(w, axis=-1)                          # (br,)
-        a = jnp.argmax(w, axis=-1).astype(jnp.int32)
-        vals_ref[:, pl.ds(j, 1)] = m[:, None]
-        idx_ref[:, pl.ds(j, 1)] = a[:, None]
-        return jnp.where(col == a[:, None], -jnp.inf, w)
+    # the peeled candidates ride in the loop carry and land in lane j by
+    # a select: Mosaic cannot store one lane at a dynamic offset
+    def peel(j, carry):
+        w, vals, idx = carry
+        m, a = _argmax_first(w, col)                     # (br, 1) each
+        vals = jnp.where(cand == j, m, vals)
+        idx = jnp.where(cand == j, a, idx)
+        return jnp.where(col == a, -jnp.inf, w), vals, idx
 
-    jax.lax.fori_loop(0, cands, peel, work)
+    _, vals, idx = jax.lax.fori_loop(
+        0, cands, peel,
+        (work, jnp.full((br, cands), -jnp.inf, jnp.float32),
+         jnp.zeros((br, cands), jnp.int32)))             # (br, C) desc
 
-    vals = vals_ref[...]                                 # (br, C) desc
-    idx = idx_ref[...]
-    cand = jax.lax.broadcasted_iota(jnp.int32, vals.shape, 1)
     k = k_ref[...]                                       # (br, 1) int32
     keff = jnp.clip(jnp.where(k > 0, k, cands), 1, cands)
     keep = cand < keff
@@ -64,13 +77,22 @@ def _sample_kernel(x_ref, t_ref, k_ref, p_ref, g_ref, o_ref,
     m = jnp.max(masked, axis=-1, keepdims=True)
     e = jnp.where(keep, jnp.exp(masked - m), 0.0)
     probs = e / jnp.maximum(jnp.sum(e, axis=-1, keepdims=True), 1e-30)
-    exclusive = jnp.cumsum(probs, axis=-1) - probs
+
+    # inclusive prefix sum as a triangular sum, one candidate column per
+    # step (Mosaic has no cumsum); the one-hot lane reduce is exact
+    def prefix(j, acc):
+        pj = jnp.sum(jnp.where(cand == j, probs, 0.0), axis=-1,
+                     keepdims=True)
+        return acc + jnp.where(cand >= j, pj, 0.0)
+
+    inclusive = jax.lax.fori_loop(0, cands, prefix, jnp.zeros_like(probs))
+    exclusive = inclusive - probs
     keep = keep & (exclusive < p_ref[...])
     pert = jnp.where(keep, vals + g_ref[...], -jnp.inf)
-    choice = jnp.argmax(pert, axis=-1)                   # (br,)
-    sampled = jnp.sum(jnp.where(cand == choice[:, None], idx, 0),
-                      axis=-1).astype(jnp.int32)
-    o_ref[...] = jnp.where(t_ref[...][:, 0] > 0, sampled, greedy)[:, None]
+    _, choice = _argmax_first(pert, cand)                # (br, 1)
+    sampled = jnp.sum(jnp.where(cand == choice, idx, 0), axis=-1,
+                      keepdims=True)
+    o_ref[...] = jnp.where(t_ref[...] > 0, sampled, greedy)
 
 
 def default_block_rows(cols: int, vmem_budget: int = 1 << 21) -> int:
@@ -109,10 +131,6 @@ def sample_pallas(logits: jax.Array, temperature: jax.Array,
         ],
         out_specs=pl.BlockSpec((br, 1), lambda i: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((r, 1), jnp.int32),
-        scratch_shapes=[
-            pltpu.VMEM((br, cands), jnp.float32),
-            pltpu.VMEM((br, cands), jnp.int32),
-        ],
         interpret=interpret,
         name="turbo_sample",
     )(logits, t2, k2, p2, gumbel.astype(jnp.float32))

@@ -29,6 +29,7 @@ from repro.configs import get_config, get_smoke_config
 from repro.core import (BucketedCostModel, Request, ServingConfig,
                         ServingSystem)
 from repro.data import LengthDistribution, RequestGenerator
+from repro.launch.compile_cache import enable_compile_cache
 from repro.models import init_params
 from repro.runtime import BucketLadder, ContinuousEngine, InferenceEngine
 
@@ -72,17 +73,24 @@ def build_parser() -> argparse.ArgumentParser:
 def run_pool_phase(engine, cost, args, cfg) -> None:
     """Generative phase over a `repro.cluster.ReplicaPool`: same-prefix
     cohorts land on one replica each, then one replica is killed mid-run
-    and its queued sessions fail over to the siblings."""
+    and its queued sessions fail over to the siblings.  Replica ``i``
+    gets its own engine, with its weights on ``jax.devices()[i % n]``."""
     from repro.cluster import ReplicaFailure, ReplicaPool
     from repro.runtime import ContinuousEngine
     print(f"\nreplica pool: {args.gen_requests} requests over "
           f"{args.replicas} replicas (prefix-affinity routing)")
+    devices = jax.devices()
+    engines = [engine] + [
+        InferenceEngine(cfg, jax.device_put(
+            engine.params, devices[i % len(devices)]),
+            ladder=engine.ladder)
+        for i in range(1, args.replicas)]
     clients = [TurboClient(
-        ContinuousEngine(engine, max_slots=8,
+        ContinuousEngine(eng, max_slots=8,
                          cap_new=max(args.max_new_tokens, 1),
                          prefix_cache=True),
         cost_model=cost, trace=args.trace is not None)
-        for _ in range(args.replicas)]
+        for eng in engines]
     pool = ReplicaPool(clients, trace=args.trace is not None)
     gp = GenerationParams(max_new_tokens=args.max_new_tokens,
                           temperature=args.temperature,
@@ -125,6 +133,7 @@ def run_pool_phase(engine, cost, args, cfg) -> None:
 
 def main() -> None:
     args = build_parser().parse_args()
+    enable_compile_cache()
 
     cfg = get_smoke_config(args.arch) if args.smoke else get_config(
         args.arch)

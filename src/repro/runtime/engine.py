@@ -103,6 +103,17 @@ class InferenceEngine:
                  sample_candidates: Optional[int] = None) -> None:
         self.cfg = cfg
         self.params = params
+        # the one device the params live on (None when they span several):
+        # state built outside the compiled cells is made there too, so a
+        # replica placed on its own chip keeps all of its arrays on it
+        devices = {d for leaf in jax.tree.leaves(params)
+                   for d in leaf.devices()}
+        self.device = devices.pop() if len(devices) == 1 else None
+        # KV is kept in the model's dtype: the projections that make it are
+        # computed in that dtype, so a wider cache holds no more precision,
+        # and decode attention over it must hand the layer carry back in
+        # that dtype
+        self.cache_dtype = jnp.dtype(cfg.dtype)
         self.rt = rt
         self.ladder = ladder
         self.pad_id = pad_id
@@ -207,7 +218,7 @@ class InferenceEngine:
                     prompt_b: int) -> Callable:
         key = (max_len, batch_b, prompt_b)
         if key not in self._prefill_cache:
-            cfg, rt = self.cfg, self.rt
+            cfg, rt, dt = self.cfg, self.rt, self.cache_dtype
 
             @jax.jit
             def pf(params, tokens, true_lengths):
@@ -216,7 +227,7 @@ class InferenceEngine:
                     true_lengths=(true_lengths if (cfg.family not in
                                                    ("ssm", "hybrid"))
                                   else None),
-                    cache_dtype=jnp.float32)
+                    cache_dtype=dt)
 
             self._prefill_cache[key] = pf
             self.compile_count += 1
@@ -231,14 +242,14 @@ class InferenceEngine:
         compile a few cells, like any other bucket."""
         key = ("sfx", prefix_len, suffix_b, batch_b)
         if key not in self._prefill_cache:
-            cfg, rt = self.cfg, self.rt
+            cfg, rt, dt = self.cfg, self.rt, self.cache_dtype
 
             @jax.jit
             def pf(params, tokens, true_lengths, prefix_k, prefix_v):
                 return prefill_suffix(
                     cfg, params, tokens, prefix_k, prefix_v,
                     prefix_len=prefix_len, rt=rt,
-                    true_lengths=true_lengths, cache_dtype=jnp.float32)
+                    true_lengths=true_lengths, cache_dtype=dt)
 
             self._prefill_cache[key] = pf
             self.compile_count += 1
@@ -457,7 +468,7 @@ class InferenceEngine:
         the batch ladder — so the compiled-cell set stays bounded."""
         key = ("pack", pack_b, pre_b, seg_b)
         if key not in self._prefill_cache:
-            cfg, rt = self.cfg, self.rt
+            cfg, rt, dt = self.cfg, self.rt, self.cache_dtype
 
             @jax.jit
             def pf(params, tokens, seg_ids, positions, last_idx,
@@ -465,7 +476,7 @@ class InferenceEngine:
                 return prefill_packed(
                     cfg, params, tokens, seg_ids, positions, last_idx,
                     prefix_k, prefix_v, prefix_seg, prefix_pos, rt=rt,
-                    cache_dtype=jnp.float32)
+                    cache_dtype=dt)
 
             self._prefill_cache[key] = pf
             self.compile_count += 1
@@ -1879,40 +1890,8 @@ class ContinuousEngine(PipelineBackend):
     def _ensure_state(self, need_len: int) -> None:
         eng = self.engine
         if self.state is None:
-            B = self.max_slots
-            if self.kv_layout == "paged":
-                if self.block_table is None:
-                    # lazy pool: max_slots x this admission's bucket of
-                    # blocks (+ trash) — workload-derived capacity that
-                    # any mix of sequence lengths up to max_len shares
-                    self.block_table = sanitizer.make_block_manager(
-                        B * (need_len // self.block_size) + 1,
-                        self.block_size)
-                if self._prefix_enabled and self.prefix_cache is None:
-                    self.prefix_cache = RadixPrefixCache(self.block_table)
-                    self.prefix_cache.on_insert = self.on_prefix_insert
-                cache = make_paged_cache(
-                    eng.cfg, B, self.block_table.num_blocks,
-                    self.block_size, self.max_blocks, jnp.float32)
-            else:
-                if self.max_len is None:
-                    self.max_len = need_len
-                if need_len > self.max_len:
-                    raise ValueError(f"prompt+budget needs {need_len} > "
-                                     f"slot cache max_len {self.max_len}")
-                cache = make_cache(eng.cfg, B, self.max_len, jnp.float32)
-            self.state = GenState(
-                cache=cache,
-                cur=jnp.zeros((B,), jnp.int32),
-                emitted=jnp.zeros((B, self.cap_new), jnp.int32),
-                counts=jnp.zeros((B,), jnp.int32),
-                done=jnp.ones((B,), bool),
-                budget=jnp.zeros((B,), jnp.int32),
-                eos=jnp.full((B, STOP_SLOTS), -1, jnp.int32),
-                temp=jnp.zeros((B,), jnp.float32),
-                top_k=jnp.zeros((B,), jnp.int32),
-                top_p=jnp.ones((B,), jnp.float32),
-                seed=jnp.zeros((B,), jnp.int32))
+            with jax.default_device(eng.device):
+                self._make_state(need_len)
             return
         if self.kv_layout == "paged":
             return      # pool and tables are fixed-shape for life
@@ -1933,6 +1912,51 @@ class ContinuousEngine(PipelineBackend):
                 cache[k] = jnp.pad(cache[k], pad)
             self.state = replace(self.state, cache=cache)
             self.max_len = need_len
+
+    def _make_state(self, need_len: int) -> None:
+        """The slot cache and per-row control state, zeroed and committed
+        to the engine's device (made there under ``_ensure_state``'s
+        default device, so committing copies nothing).  An uncommitted
+        array would follow the next uncommitted operand of an eager op
+        back to the default device."""
+        eng = self.engine
+        B = self.max_slots
+        if self.kv_layout == "paged":
+            if self.block_table is None:
+                # lazy pool: max_slots x this admission's bucket of
+                # blocks (+ trash) — workload-derived capacity that
+                # any mix of sequence lengths up to max_len shares
+                self.block_table = sanitizer.make_block_manager(
+                    B * (need_len // self.block_size) + 1,
+                    self.block_size)
+            if self._prefix_enabled and self.prefix_cache is None:
+                self.prefix_cache = RadixPrefixCache(self.block_table)
+                self.prefix_cache.on_insert = self.on_prefix_insert
+            cache = make_paged_cache(
+                eng.cfg, B, self.block_table.num_blocks,
+                self.block_size, self.max_blocks, eng.cache_dtype)
+        else:
+            if self.max_len is None:
+                self.max_len = need_len
+            if need_len > self.max_len:
+                raise ValueError(f"prompt+budget needs {need_len} > "
+                                 f"slot cache max_len {self.max_len}")
+            cache = make_cache(eng.cfg, B, self.max_len, eng.cache_dtype)
+        fields = dict(
+            cache=cache,
+            cur=jnp.zeros((B,), jnp.int32),
+            emitted=jnp.zeros((B, self.cap_new), jnp.int32),
+            counts=jnp.zeros((B,), jnp.int32),
+            done=jnp.ones((B,), bool),
+            budget=jnp.zeros((B,), jnp.int32),
+            eos=jnp.full((B, STOP_SLOTS), -1, jnp.int32),
+            temp=jnp.zeros((B,), jnp.float32),
+            top_k=jnp.zeros((B,), jnp.int32),
+            top_p=jnp.ones((B,), jnp.float32),
+            seed=jnp.zeros((B,), jnp.int32))
+        if eng.device is not None:
+            fields = jax.device_put(fields, eng.device)
+        self.state = GenState(**fields)
 
     def _spliced(self, cache: Dict[str, jax.Array], rows: GenState,
                  idx: jax.Array, k: int) -> GenState:

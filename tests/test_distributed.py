@@ -29,6 +29,7 @@ def run_sub(code: str) -> str:
 def test_sharded_train_step_matches_single_device():
     code = textwrap.dedent("""
         import jax, jax.numpy as jnp, numpy as np
+        AUTO = jax.sharding.AxisType.Auto
         from functools import partial
         from repro.configs import get_smoke_config
         from repro.distributed import plan as dplan
@@ -48,7 +49,8 @@ def test_sharded_train_step_matches_single_device():
         # single device reference
         s_ref, m_ref = jax.jit(step)(state, batch)
 
-        mesh = jax.make_mesh((2, 4), ("data", "model"))
+        mesh = jax.make_mesh((2, 4), ("data", "model"),
+                             axis_types=(AUTO, AUTO))
         rules = make_rules(mesh)
         with sharding_rules(rules):
             astate = jax.eval_shape(lambda: init_state(cfg, tc, 0))
@@ -77,6 +79,7 @@ def test_sharded_train_step_matches_single_device():
 def test_decode_sharded_matches_single_device():
     code = textwrap.dedent("""
         import jax, jax.numpy as jnp, numpy as np
+        AUTO = jax.sharding.AxisType.Auto
         from repro.configs import get_smoke_config
         from repro.distributed import plan as dplan
         from repro.distributed.sharding import make_rules, sharding_rules
@@ -92,7 +95,8 @@ def test_decode_sharded_matches_single_device():
         nxt = jnp.argmax(logits, -1)
         ref, _ = decode_step(cfg, params, dict(cache), nxt)
 
-        mesh = jax.make_mesh((2, 4), ("data", "model"))
+        mesh = jax.make_mesh((2, 4), ("data", "model"),
+                             axis_types=(AUTO, AUTO))
         rules = make_rules(mesh)
         with sharding_rules(rules):
             p_sh = dplan.to_shardings(
@@ -136,6 +140,7 @@ def test_elastic_reshard_across_mesh_sizes():
     a continuation on the original mesh (loss parity)."""
     code = textwrap.dedent("""
         import jax, jax.numpy as jnp, numpy as np, tempfile
+        AUTO = jax.sharding.AxisType.Auto
         from functools import partial
         from repro.configs import get_smoke_config
         from repro.distributed import plan as dplan
@@ -153,7 +158,8 @@ def test_elastic_reshard_across_mesh_sizes():
         batch = synthetic_train_batch(cfg, jax.random.key(1), 4, 32)
 
         def run_on(mesh_shape, state_tree, n):
-            mesh = jax.make_mesh(mesh_shape, ("data", "model"))
+            mesh = jax.make_mesh(mesh_shape, ("data", "model"),
+                                 axis_types=(AUTO, AUTO))
             rules = make_rules(mesh)
             with sharding_rules(rules):
                 astate = jax.eval_shape(lambda: init_state(cfg, tc, 0))
@@ -193,13 +199,15 @@ def test_elastic_reshard_across_mesh_sizes():
 def test_param_specs_always_divide():
     code = textwrap.dedent("""
         import jax
+        AUTO = jax.sharding.AxisType.Auto
         from functools import partial
         from repro.configs import ARCH_IDS, get_config
         from repro.distributed import plan as dplan
         from repro.distributed.sharding import make_rules
         from repro.models.transformer import init_params
 
-        mesh = jax.make_mesh((2, 4), ("data", "model"))
+        mesh = jax.make_mesh((2, 4), ("data", "model"),
+                             axis_types=(AUTO, AUTO))
         rules = make_rules(mesh)
         sizes = dict(mesh.shape)
         for arch in ARCH_IDS:

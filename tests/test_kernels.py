@@ -306,6 +306,21 @@ def test_sample_one_hot_logits():
         np.testing.assert_array_equal(np.asarray(got), hot)
 
 
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
+def test_sample_tie_order_lowest_index(dtype):
+    """Rows full of exact ties (as bf16 logits are) peel candidates and
+    pick the greedy token lowest index first — the order of jnp.argmax
+    and lax.top_k — so kernel and oracle draw the same token."""
+    rows, cols = 16, 512
+    logits, t, k, p, g = _sampling_inputs(
+        rows, cols, 5, temps=(0.0, 0.8, 1.2), ks=(0, 3, 40),
+        ps=(0.9, 1.0))
+    logits = (jnp.round(logits) / 2).astype(dtype)    # few distinct values
+    want = ops.fused_sample(logits, t, k, p, g, impl="xla")
+    got = ops.fused_sample(logits, t, k, p, g, impl="interpret")
+    np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+
+
 @pytest.mark.parametrize("rows,block_rows", [(33, 8), (5, 0), (100, 16)])
 def test_sample_rows_not_multiple_of_block(rows, block_rows):
     """Ragged tail blocks (rows not tiling the grid) must still match

@@ -50,7 +50,12 @@ def _virtual_pipeline(config: SimConfig, cost=CM):
 
 def test_packed_flat_matches_single_segment(engine):
     """The same suffix packed alone vs packed beside another segment
-    produces identical last-token logits — segment masking is exact."""
+    produces the same last-token logits — segment masking is exact.
+
+    The pack widths differ, and XLA picks its f32 reduction order by
+    shape, so the logits may differ in the last bits (4e-7 seen).  A
+    leak across segments would move them by orders of magnitude more
+    than the f32 tolerance below."""
     a = [5, 9, 13, 2, 7]
     b = [3, 3, 8, 1]
     cfg = engine.cfg
@@ -62,8 +67,9 @@ def test_packed_flat_matches_single_segment(engine):
     lab, _ = engine.prefill_packed_flat([a, b], [0, 0], zero, zero,
                                         zseg, zseg)
     lb, _ = engine.prefill_packed_flat([b], [0], zero, zero, zseg, zseg)
-    np.testing.assert_array_equal(np.asarray(la[0]), np.asarray(lab[0]))
-    np.testing.assert_array_equal(np.asarray(lb[0]), np.asarray(lab[1]))
+    tol = dict(rtol=1e-5, atol=1e-5)
+    np.testing.assert_allclose(np.asarray(la[0]), np.asarray(lab[0]), **tol)
+    np.testing.assert_allclose(np.asarray(lb[0]), np.asarray(lab[1]), **tol)
 
 
 def test_packed_flat_requires_fresh_tokens(engine):
