@@ -215,11 +215,15 @@ class TurboClient:
         cost = cost_model if cost_model is not None \
             else AnalyticCostModel(**_DEFAULT_COST)
         # observability: metrics always on; tracing per `trace` (True
-        # for a default recorder, or bring your own TraceRecorder)
+        # for a default recorder whose spans are mirrored onto the JAX
+        # profiler's host plane, or bring your own TraceRecorder)
         if isinstance(trace, TraceRecorder):
             obs = Observability(trace=trace)
+        elif trace:
+            from jax.profiler import TraceAnnotation
+            obs = Observability.with_trace(annotate=TraceAnnotation)
         else:
-            obs = Observability.with_trace() if trace else Observability()
+            obs = Observability()
         self.obs = obs
         self.pipeline = ServingPipeline(
             backend, cost, config if config is not None

@@ -44,7 +44,7 @@ from typing import Callable, Dict, List, Optional, Sequence, Tuple
 from repro.core.cost_model import CostModel, chunk_tokens_for_budget
 from repro.core.scheduler import (BatchPlan, dp_schedule, naive_schedule,
                                   nobatch_schedule)
-from repro.obs import Observability
+from repro.obs import Observability, span_of
 from repro.runtime.session import Session, SessionState
 
 # NOTE: repro.runtime.sanitizer is imported lazily (it subclasses
@@ -299,7 +299,15 @@ class ServingPipeline:
         # event per executed tick, timestamped by self.clock so wall
         # and virtual clocks yield structurally identical traces.
         # Recording touches host scalars only — never a device value.
+        # The recorder's phase spans take the same clock, and a backend
+        # with phases of its own (duck-typed ``attach_trace``) records
+        # them into the same recorder.
         self.obs = obs if obs is not None else Observability()
+        if self.obs.trace is not None:
+            self.obs.trace.clock = clock
+        attach = getattr(backend, "attach_trace", None)
+        if attach is not None:
+            attach(self.obs.trace)
         m = self.obs.metrics
         self._stat = {f: m.counter("pipeline." + f) for f in STAT_FIELDS}
         self._veto = {r: m.counter("pipeline.veto." + r)
@@ -319,7 +327,6 @@ class ServingPipeline:
         self._c_pack_segs = m.counter("pipeline.pack.segments")
         self._hist_pack = m.histogram("pipeline.pack.occupancy")
         self._trace_ids = itertools.count(1)
-        self._last_compile_count = 0
         # did the last tick execute work (prefill/chunk/decode)?  The
         # no-progress guard in drain() reads this instead of counters,
         # so it keeps working even under a disabled registry.
@@ -578,7 +585,23 @@ class ServingPipeline:
     def tick(self) -> List[Session]:
         """One scheduler iteration: a resumable-prefill chunk advance, a
         prefill admission round, OR one decode step over every in-flight
-        sequence.  Returns the sessions that finished during this tick."""
+        sequence.  Returns the sessions that finished during this tick.
+        With tracing on, the tick takes an id first: the phase spans
+        recorded inside it name that id as their parent.  A tick that
+        executes nothing records no tick event, so its spans go too."""
+        trace = self.obs.trace
+        if trace is None:
+            return self._tick(None)
+        start = len(trace.events)
+        tick_id = trace.begin()
+        try:
+            return self._tick(tick_id)
+        finally:
+            trace.end(tick_id)
+            if not self._tick_worked:
+                trace.discard(tick_id, start)
+
+    def _tick(self, tick_id: Optional[int]) -> List[Session]:
         done: List[Session] = []
         self._tick_worked = False
         t0 = self.clock()
@@ -608,19 +631,21 @@ class ServingPipeline:
                 self._stat["decode_ticks"].inc()
                 kind = "chunk+decode"
         else:
-            decision = self._admission_decision(record=True)
-            if decision == "defer":
-                self._stat["deferred_prefills"].inc()
-                decision = None
-            if decision is not None and decision[0] == "plan" and \
-                    self._pack_enabled() and self.chunking and \
-                    decision[1][0].seq_len <= self._chunk_tokens() // 2:
-                # resumable prefills are in flight and the queue head
-                # fits the next pack's admission room: let the shorts
-                # ride that pack turn instead of paying their own
-                # dispatch here — the decode batch advances meanwhile
-                self._veto["pack_wait"].inc()
-                decision = None
+            with span_of(self.obs.trace, "sched.admit"):
+                decision = self._admission_decision(record=True)
+                if decision == "defer":
+                    self._stat["deferred_prefills"].inc()
+                    decision = None
+                if decision is not None and decision[0] == "plan" and \
+                        self._pack_enabled() and self.chunking and \
+                        decision[1][0].seq_len <= \
+                        self._chunk_tokens() // 2:
+                    # resumable prefills are in flight and the queue head
+                    # fits the next pack's admission room: let the shorts
+                    # ride that pack turn instead of paying their own
+                    # dispatch here — the decode batch advances meanwhile
+                    self._veto["pack_wait"].inc()
+                    decision = None
             if decision is not None:
                 dkind, payload, plan = decision
                 if dkind == "chunk":
@@ -649,9 +674,10 @@ class ServingPipeline:
             # actually generated
             del s.token_times[len(s.generated):]
         self.finished.extend(done)
-        self._deliver_tokens(done)
+        with span_of(self.obs.trace, "pipeline.deliver"):
+            self._deliver_tokens(done)
         self._emit_finished(done)
-        self._tick_boundary(kind, t0, len(decoding))
+        self._tick_boundary(kind, t0, len(decoding), tick_id)
         if self._sanitize:
             self._check_invariants(done)
         return done
@@ -699,19 +725,20 @@ class ServingPipeline:
         return "stop"            # eos / stop id / synthetic eos_at
 
     def _tick_boundary(self, kind: Optional[str], t0: float,
-                       decode_batch: int) -> None:
+                       decode_batch: int,
+                       tick_id: Optional[int] = None) -> None:
         """Tick-boundary recording: scheduler gauges, the tick-duration
         histogram, backend gauge sampling (duck-typed
         ``observe_metrics`` — host ints only, never a device read), and
         the tick's trace slice.  ``kind`` is None when the tick
         executed nothing (empty pipeline / un-triggered lazy queue)."""
-        m = self.obs.metrics
-        self._g_queue.set(len(self.queue))
-        self._g_batch.set(len(self.live))
-        self._g_chunking.set(len(self.chunking))
-        observe = getattr(self.backend, "observe_metrics", None)
-        if observe is not None:
-            observe(m)
+        with span_of(self.obs.trace, "pipeline.observe"):
+            self._g_queue.set(len(self.queue))
+            self._g_batch.set(len(self.live))
+            self._g_chunking.set(len(self.chunking))
+            observe = getattr(self.backend, "observe_metrics", None)
+            if observe is not None:
+                observe(self.obs.metrics)
         if kind is None:
             return
         self._tick_worked = True
@@ -719,13 +746,8 @@ class ServingPipeline:
         self._hist_tick.observe(t1 - t0)
         trace = self.obs.trace
         if trace is not None:
-            trace.tick(kind, t0, t1, batch=decode_batch,
+            trace.tick(kind, t0, t1, tick_id, batch=decode_batch,
                        queue=len(self.queue), live=len(self.live))
-            cc = m.gauge("engine.compile_count").value
-            if cc > self._last_compile_count:
-                trace.record("compile", "engine", t1,
-                             n=cc - self._last_compile_count)
-            self._last_compile_count = cc
 
     def _record_splice(self, s: Session) -> None:
         """A session just spliced into decode: its seed token exists, so
@@ -790,9 +812,11 @@ class ServingPipeline:
         """The classic admission round: plan over ``cand`` (reusing the
         plan the veto already priced, when there is one), dispatch."""
         if plan is None:
-            plan = plan_for_policy(self.config.policy,
-                                   [s.seq_len for s in cand], self.cost,
-                                   self.config.max_batch_size)
+            with span_of(self.obs.trace, "sched.admit"):
+                plan = plan_for_policy(self.config.policy,
+                                       [s.seq_len for s in cand],
+                                       self.cost,
+                                       self.config.max_batch_size)
         batches = plan.batches
         # with decodes in flight, dispatch ONE batch per tick: the
         # two-phase veto bounded the stall of a single prefill pass,
@@ -954,46 +978,47 @@ class ServingPipeline:
         N decode stalls.  Returns True when the pack was fused with a
         decode tick (non-splicing packs only, like ``_advance_chunk``).
         """
-        budget = self._chunk_tokens()
-        quantum = self.backend.chunk_quantum()
-        # queued prompts claim part of the budget as whole admissions
-        # FIRST — half when resumable prefills also need the turn, all
-        # of it otherwise.  This is what makes the pack pay off: the
-        # shorts that would have cost their own prefill dispatch on the
-        # alternate tick ride the chunk turn instead (same stall bound:
-        # the pack is ONE dispatch priced over its flat tokens).
-        admissions: List[Session] = []
-        if self.queue and self._trigger():
-            room = budget if not self.chunking else budget // 2
-            for s in self._admissible():
-                if len(admissions) >= self.config.max_batch_size:
-                    break
-                if s.seq_len > room:
-                    break            # FIFO: nobody overtakes the head
-                admissions.append(s)
-                room -= s.seq_len
-        used_adm = sum(s.seq_len for s in admissions)
-        chunks: List[Tuple[Session, int]] = []
-        used = 0
-        if self.chunking:
-            rot = self._chunk_rr % len(self.chunking)
-            self._chunk_rr += 1
-            order = self.chunking[rot:] + self.chunking[:rot]
-            left = max(budget - used_adm, quantum)
-            share = max((left // len(order)) // quantum * quantum,
-                        quantum)
-            for s in order:
-                if chunks and used + quantum > left:
-                    break            # rotation reaches it next turn
-                upto = min(s.prefilled_tokens + share, s.seq_len)
-                chunks.append((s, upto))
-                used += upto - s.prefilled_tokens
-        if not chunks and not admissions:
-            return False
-        finals = [s for s, upto in chunks if upto == s.seq_len]
-        fused = bool(decoding) and not admissions and not finals and \
-            self.config.fused_chunk_decode and \
-            self.backend.supports_fused_chunk_decode()
+        with span_of(self.obs.trace, "sched.admit"):
+            budget = self._chunk_tokens()
+            quantum = self.backend.chunk_quantum()
+            # queued prompts claim part of the budget as whole admissions
+            # FIRST — half when resumable prefills also need the turn, all
+            # of it otherwise.  This is what makes the pack pay off: the
+            # shorts that would have cost their own prefill dispatch on the
+            # alternate tick ride the chunk turn instead (same stall bound:
+            # the pack is ONE dispatch priced over its flat tokens).
+            admissions: List[Session] = []
+            if self.queue and self._trigger():
+                room = budget if not self.chunking else budget // 2
+                for s in self._admissible():
+                    if len(admissions) >= self.config.max_batch_size:
+                        break
+                    if s.seq_len > room:
+                        break            # FIFO: nobody overtakes the head
+                    admissions.append(s)
+                    room -= s.seq_len
+            used_adm = sum(s.seq_len for s in admissions)
+            chunks: List[Tuple[Session, int]] = []
+            used = 0
+            if self.chunking:
+                rot = self._chunk_rr % len(self.chunking)
+                self._chunk_rr += 1
+                order = self.chunking[rot:] + self.chunking[:rot]
+                left = max(budget - used_adm, quantum)
+                share = max((left // len(order)) // quantum * quantum,
+                            quantum)
+                for s in order:
+                    if chunks and used + quantum > left:
+                        break            # rotation reaches it next turn
+                    upto = min(s.prefilled_tokens + share, s.seq_len)
+                    chunks.append((s, upto))
+                    used += upto - s.prefilled_tokens
+            if not chunks and not admissions:
+                return False
+            finals = [s for s, upto in chunks if upto == s.seq_len]
+            fused = bool(decoding) and not admissions and not finals and \
+                self.config.fused_chunk_decode and \
+                self.backend.supports_fused_chunk_decode()
         trace = self.obs.trace
         prev = {s.req_id: s.prefilled_tokens for s, _ in chunks}
         now = self.clock()

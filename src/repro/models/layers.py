@@ -4,6 +4,9 @@
 All functions are pure; parameters are plain dicts of jnp arrays so they
 stack cleanly along a leading layer dim for ``lax.scan``. Activation
 sharding uses logical-axis annotations (`repro.distributed.constrain`).
+Each layer runs under a ``jax.named_scope`` (``norm``, ``qkv``,
+``attention``, ``expand_kv``, ``out_proj``, ``ffn``, ``embed``,
+``lm_head``), so every device operation in a profile names its layer.
 """
 from __future__ import annotations
 
@@ -41,6 +44,7 @@ def init_norm(cfg: ModelConfig, dim: int, dtype) -> Params:
     return p
 
 
+@jax.named_scope("norm")
 def apply_norm(cfg: ModelConfig, p: Params, x: jax.Array,
                eps: float = 1e-6) -> jax.Array:
     """LayerNorm via the paper's Eq.1 single-pass form, or RMSNorm.
@@ -178,6 +182,7 @@ def init_attention(cfg: ModelConfig, key, dtype) -> Params:
     return p
 
 
+@jax.named_scope("qkv")
 def qkv_project(cfg: ModelConfig, p: Params, x: jax.Array,
                 positions: jax.Array):
     """x: (B,S,d) -> q (B,S,H,dh), k/v (B,S,KV,dh) with norm+rope applied."""
@@ -195,6 +200,7 @@ def qkv_project(cfg: ModelConfig, p: Params, x: jax.Array,
     return q, k, v
 
 
+@jax.named_scope("expand_kv")
 def expand_kv(x: jax.Array, groups: int,
               constrain_heads: bool = True) -> jax.Array:
     """GQA -> MHA: repeat each kv head `groups` times so the head dim stays
@@ -214,6 +220,7 @@ def expand_kv(x: jax.Array, groups: int,
     return x
 
 
+@jax.named_scope("attention")
 def attention_naive(cfg: ModelConfig, q, k, v, *, causal: bool = True,
                     q_offset: int = 0) -> jax.Array:
     """Reference attention. q:(B,Sq,H,dh), k/v:(B,Sk,KV,dh) -> (B,Sq,H,dh)."""
@@ -233,6 +240,7 @@ def attention_naive(cfg: ModelConfig, q, k, v, *, causal: bool = True,
     return out
 
 
+@jax.named_scope("attention")
 def attention_packed(cfg: ModelConfig, q, k, v, *, q_seg, k_seg,
                      q_pos, k_pos) -> jax.Array:
     """Segment-masked causal attention for packed prefill.
@@ -269,6 +277,7 @@ def attention_packed(cfg: ModelConfig, q, k, v, *, q_seg, k_seg,
     return out
 
 
+@jax.named_scope("attention")
 def attention_chunked(cfg: ModelConfig, q, k, v, *, causal: bool = True,
                       q_block: int = 512, kv_block: int = 1024,
                       q_offset: int = 0) -> jax.Array:
@@ -351,6 +360,7 @@ def attention_chunked(cfg: ModelConfig, q, k, v, *, causal: bool = True,
     return out[:, :sq]
 
 
+@jax.named_scope("attention")
 def attention_chunked_train(cfg: ModelConfig, q, k, v, *,
                             causal: bool = True, q_block: int = 512
                             ) -> jax.Array:
@@ -390,6 +400,7 @@ def attention_chunked_train(cfg: ModelConfig, q, k, v, *,
     return out[:, :sq]
 
 
+@jax.named_scope("attention")
 def attention_decode(cfg: ModelConfig, q, k_cache, v_cache, cache_len
                      ) -> jax.Array:
     """Decode attention: q (B,1,H,dh) against cache (B,S,KV,dh).
@@ -424,6 +435,7 @@ def attention_decode(cfg: ModelConfig, q, k_cache, v_cache, cache_len
     return out[:, None]
 
 
+@jax.named_scope("out_proj")
 def attention_output(p: Params, attn: jax.Array) -> jax.Array:
     out = jnp.einsum("bshk,hkd->bsd", attn, p["wo"])
     return constrain(out, "batch", None, "embed")
@@ -453,6 +465,7 @@ def init_ffn(cfg: ModelConfig, key, dtype, d_ff: Optional[int] = None
     }
 
 
+@jax.named_scope("ffn")
 def apply_ffn(cfg: ModelConfig, p: Params, x: jax.Array) -> jax.Array:
     if cfg.act == "swiglu":
         g = jnp.einsum("bsd,df->bsf", x, p["w_gate"])
@@ -484,6 +497,7 @@ def init_embedding(cfg: ModelConfig, key, dtype) -> Params:
     return p
 
 
+@jax.named_scope("embed")
 def embed_tokens(cfg: ModelConfig, p: Params, tokens: jax.Array
                  ) -> jax.Array:
     """tokens: (B,S) or (B,K,S) for multi-codebook audio -> (B,S,d)."""
@@ -497,6 +511,7 @@ def embed_tokens(cfg: ModelConfig, p: Params, tokens: jax.Array
     return constrain(h, "batch", None, "embed")
 
 
+@jax.named_scope("lm_head")
 def lm_logits(cfg: ModelConfig, p: Params, h: jax.Array) -> jax.Array:
     """h: (B,S,d) -> logits (B,S,V) or (B,K,S,V) for audio."""
     if cfg.tie_embeddings:

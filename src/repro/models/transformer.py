@@ -622,6 +622,7 @@ def decode_step(cfg: ModelConfig, params: Params, cache: Dict[str, Any],
     return logits[:, 0], new_cache
 
 
+@jax.named_scope("kv_write")
 def _write_kv(k_cache, v_cache, k, v, pos):
     """k_cache: (B,S,KV,dh); k: (B,1,KV,dh); pos: (B,) uniform write index."""
     def upd(cache, new):
@@ -657,6 +658,7 @@ def _decode_attn(cfg, params, cache, h, positions, rt):
     return new_cache, h
 
 
+@jax.named_scope("kv_write")
 def _paged_write_kv(k_pool, v_pool, k, v, tables, pos):
     """Scatter one new token per row into the paged pool.
 
@@ -679,6 +681,7 @@ def _paged_write_kv(k_pool, v_pool, k, v, tables, pos):
     return upd(k_pool, k), upd(v_pool, v)
 
 
+@jax.named_scope("paged_gather")
 def _paged_gather(pool, tables):
     """Materialize each row's logical KV view from the pool:
     (NB, BS, KV, dh) x (B, MB) -> (B, MB*BS, KV, dh).  Positions beyond a

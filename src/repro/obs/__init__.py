@@ -6,12 +6,12 @@ Zero dependencies (no jax/numpy) and host-scalars-only by design: the
 tick loop records here without ever forcing a device->host sync.
 """
 from repro.obs.metrics import Counter, Gauge, Histogram, MetricsRegistry
-from repro.obs.trace import (TERMINAL_EVENTS, TraceRecorder,
-                             chrome_trace, save_chrome_trace)
+from repro.obs.trace import (TERMINAL_EVENTS, TraceRecorder, chrome_trace,
+                             save_chrome_trace, span_of)
 
 __all__ = ["Counter", "Gauge", "Histogram", "MetricsRegistry",
            "Observability", "TraceRecorder", "TERMINAL_EVENTS",
-           "chrome_trace", "save_chrome_trace"]
+           "chrome_trace", "save_chrome_trace", "span_of"]
 
 
 class Observability:
@@ -26,7 +26,7 @@ class Observability:
         self.trace = trace
 
     @classmethod
-    def with_trace(cls, max_events: int = None) -> "Observability":
-        rec = TraceRecorder() if max_events is None \
-            else TraceRecorder(max_events=max_events)
-        return cls(trace=rec)
+    def with_trace(cls, max_events: int = None,
+                   annotate=None) -> "Observability":
+        kw = {} if max_events is None else {"max_events": max_events}
+        return cls(trace=TraceRecorder(annotate=annotate, **kw))

@@ -39,7 +39,8 @@ from repro.models import (ModelRuntime, DEFAULT_RUNTIME, decode_step,
                           forward_hidden, make_cache, make_paged_cache,
                           prefill, prefill_packed, prefill_suffix)
 from repro.models.layers import lm_logits
-from repro.runtime import sanitizer
+from repro.obs import span_of
+from repro.runtime import lowering, sanitizer
 from repro.runtime.bucketing import BucketLadder
 from repro.runtime.kv_cache import (DEFAULT_KV_BLOCK, BlockExhausted,
                                     BlockTableManager, KVSlabManager,
@@ -189,13 +190,15 @@ class InferenceEngine:
                 prev_len = cache["len"]
                 logits, cache2 = decode_step(cfg, params, cache, cur,
                                              rt=rt)
-                if sampling and tok_ndim == 1:
-                    nxt = sample_tokens(logits, temperature=temp,
-                                        top_k=top_k, top_p=top_p,
-                                        seed=seed, step=counts,
-                                        candidates=cands)
-                else:
-                    nxt = jnp.argmax(logits, axis=-1).astype(jnp.int32)
+                with jax.named_scope("sample"):
+                    if sampling and tok_ndim == 1:
+                        nxt = sample_tokens(logits, temperature=temp,
+                                            top_k=top_k, top_p=top_p,
+                                            seed=seed, step=counts,
+                                            candidates=cands)
+                    else:
+                        nxt = jnp.argmax(logits, axis=-1).astype(
+                            jnp.int32)
                 tok = nxt if nxt.ndim == 1 else nxt[:, 0]
                 # finished rows are frozen: no KV advance, no emission
                 cache2["len"] = jnp.where(done, prev_len, cache2["len"])
@@ -769,8 +772,21 @@ class ContinuousEngine(PipelineBackend):
         # generate() negative ids; decremented per warm session)
         self._warm_id = -(10 ** 9)
         self.warmup_stats: Optional[Dict[str, float]] = None
+        # phase spans go to the serving pipeline's trace recorder
+        # (`attach_trace`); None keeps every span site a shared no-op
+        self.trace = None
+        lowering.install()
 
     # -- PipelineBackend -------------------------------------------------
+    def attach_trace(self, recorder) -> None:
+        """The duck-typed hook `ServingPipeline` calls with its trace
+        recorder (None when tracing is off): the engine's phase spans
+        and the ``jax.lower`` spans of programs lowered inside them are
+        recorded there."""
+        self.trace = recorder
+        if recorder is not None:
+            lowering.watch(recorder)
+
     def free_slots(self) -> int:
         return sum(1 for s in self.sessions if s is None) \
             - len(self._chunk_slots)
@@ -779,8 +795,11 @@ class ContinuousEngine(PipelineBackend):
         """Tick-boundary gauge sampling for the observability registry
         (the duck-typed hook `ServingPipeline._tick_boundary` calls).
         Every value set here is host-side Python bookkeeping the engine
-        already maintains — no device value is ever read."""
-        m.gauge("engine.compile_count").set(self.engine.compile_count)
+        already maintains — no device value is ever read.  The
+        lowering totals are the process's (`repro.runtime.lowering`)."""
+        low = lowering.totals()
+        m.gauge("engine.lowerings").set(low["lowerings"])
+        m.gauge("engine.lowering_seconds").set(low["seconds"])
         m.gauge("engine.prefill_tokens").set(self.prefill_tokens)
         m.gauge("engine.prefill_dispatches").set(self.prefill_dispatches)
         m.gauge("engine.decode_ticks").set(self.decode_ticks)
@@ -1042,30 +1061,33 @@ class ContinuousEngine(PipelineBackend):
                     if part_matches is not None else 0
                 if cached:
                     pk, pv = self._gather_prefix(part_matches, cached)
-                    rows = eng.prefill_suffix_batch(
-                        [list(s.prompt) for s in part_sessions],
-                        prefix_k=pk, prefix_v=pv, prefix_len=cached,
-                        max_new_tokens=[s.max_new_tokens
-                                        for s in part_sessions],
-                        eos_id=[s.eos_id for s in part_sessions],
-                        cap_new=self.cap_new,
-                        sampling=[s.params for s in part_sessions])
-                else:
-                    prefill_len = need if self.kv_layout == "paged" \
-                        else self.max_len
-                    rows = eng.prefill_batch(
-                        [list(s.prompt) for s in part_sessions],
-                        max_len=prefill_len,
-                        max_new_tokens=[s.max_new_tokens
-                                        for s in part_sessions],
-                        eos_id=[s.eos_id for s in part_sessions],
-                        cap_new=self.cap_new,
-                        sampling=[s.params for s in part_sessions])
-                if self.kv_layout == "paged":
-                    self._splice_paged(rows, part_slots, part_sessions,
-                                       part_matches)
-                else:
-                    self._splice(rows, part_slots)
+                with span_of(self.trace, "engine.dispatch"):
+                    if cached:
+                        rows = eng.prefill_suffix_batch(
+                            [list(s.prompt) for s in part_sessions],
+                            prefix_k=pk, prefix_v=pv, prefix_len=cached,
+                            max_new_tokens=[s.max_new_tokens
+                                            for s in part_sessions],
+                            eos_id=[s.eos_id for s in part_sessions],
+                            cap_new=self.cap_new,
+                            sampling=[s.params for s in part_sessions])
+                    else:
+                        prefill_len = need if self.kv_layout == "paged" \
+                            else self.max_len
+                        rows = eng.prefill_batch(
+                            [list(s.prompt) for s in part_sessions],
+                            max_len=prefill_len,
+                            max_new_tokens=[s.max_new_tokens
+                                            for s in part_sessions],
+                            eos_id=[s.eos_id for s in part_sessions],
+                            cap_new=self.cap_new,
+                            sampling=[s.params for s in part_sessions])
+                with span_of(self.trace, "engine.splice"):
+                    if self.kv_layout == "paged":
+                        self._splice_paged(rows, part_slots,
+                                           part_sessions, part_matches)
+                    else:
+                        self._splice(rows, part_slots)
                 self.prefill_dispatches += 1
                 self.prefill_tokens += sum(s.seq_len - cached
                                            for s in part_sessions)
@@ -1115,8 +1137,10 @@ class ContinuousEngine(PipelineBackend):
 
     def decode_tick(self, sessions: List[Session]) -> None:
         if self.kv_layout == "paged":
-            self._append_blocks()
-        self.state = self.engine.decode_step_batch(self.state)
+            with span_of(self.trace, "engine.blocks"):
+                self._append_blocks()
+        with span_of(self.trace, "engine.dispatch"):
+            self.state = self.engine.decode_step_batch(self.state)
         self.decode_ticks += 1
         self._since_sync += 1
         if self._since_sync >= self.sync_every:
@@ -1134,12 +1158,14 @@ class ContinuousEngine(PipelineBackend):
                   if s is not None and s.stream]
         if not wanted:
             return
-        # turbolint: allow-sync(per-tick streaming flush for stream=True rows)
-        counts = np.asarray(self.state.counts)
-        # turbolint: allow-sync(per-tick streaming flush for stream=True rows)
-        emitted = np.asarray(self.state.emitted)
-        for slot, s in wanted:
-            s.generated = [int(x) for x in emitted[slot, :counts[slot]]]
+        with span_of(self.trace, "engine.stream"):
+            # turbolint: allow-sync(per-tick streaming flush for stream=True rows)
+            counts = np.asarray(self.state.counts)
+            # turbolint: allow-sync(per-tick streaming flush for stream=True rows)
+            emitted = np.asarray(self.state.emitted)
+            for slot, s in wanted:
+                s.generated = [int(x)
+                               for x in emitted[slot, :counts[slot]]]
 
     # -- AOT warmup ------------------------------------------------------
     def warmup_aot(self, progress: Optional[Callable[[int], None]] = None
@@ -1378,232 +1404,236 @@ class ContinuousEngine(PipelineBackend):
                     [w for k, w in grp if k == "c"],
                     decoding if last else None)
             return
-        # ---- admission pre-checks (nothing mutated before they pass) --
-        over = [s.req_id for s in admissions
-                if s.max_new_tokens > self.cap_new]
-        if over:
-            raise ValueError(
-                f"sessions {over} exceed the emission buffer "
-                f"(max_new_tokens > cap_new={self.cap_new}); raise "
-                f"cap_new or lower the budget")
-        dup = [s.req_id for s in admissions
-               if eng.kv_slab.has_region(s.req_id)]
-        if dup:
-            raise ValueError(f"req_ids {dup} already hold KV regions "
-                             "(duplicate in-flight submission?)")
-        if admissions:
-            need = eng.ladder.seq_bucket(
-                max(s.total_len for s in admissions))
-            self._ensure_state(need)
-        taken = set(self._chunk_slots.values())
-        slots = [i for i, s in enumerate(self.sessions)
-                 if s is None and i not in taken][:len(admissions)]
-        assert len(slots) == len(admissions), "admitted beyond free slots"
-        matches: Optional[List[PrefixMatch]] = None
-        if self.prefix_cache is not None and admissions:
-            matches = [self.prefix_cache.match(list(s.prompt))
-                       for s in admissions]
-        btm = self.block_table
-        if admissions:
-            want = 0
-            for i, s in enumerate(admissions):
-                covered = len(matches[i].full_blocks) if matches else 0
-                want += btm.blocks_needed(s.total_len) - covered
-            deficit = want + sum(self._reserved.values()) - \
-                btm.free_blocks
-            if deficit > 0 and self.prefix_cache is not None:
-                deficit -= self.prefix_cache.evict(deficit)
-            if deficit > 0:
-                if matches:
-                    for m in matches:
-                        self.prefix_cache.release(m)
+        with span_of(self.trace, "engine.pack"):
+            # ---- admission pre-checks (nothing mutated before they pass) --
+            over = [s.req_id for s in admissions
+                    if s.max_new_tokens > self.cap_new]
+            if over:
                 raise ValueError(
-                    f"packed prefill needs {want} fresh KV blocks beyond "
-                    f"reservations, pool has {btm.free_blocks} free — "
-                    "the admission planner should have vetoed this pack")
-        # ---- chunk validation + block ensure (reserved at admission,
-        # so ensure cannot exhaust the pool) -----------------------------
-        for s, upto in chunks:
-            req = s.req_id
-            off = s.prefilled_tokens
-            if req not in self._chunk_slots:
-                raise ValueError(f"session {req} has no chunked prefill "
-                                 "in flight")
-            if not off < upto <= s.seq_len:
-                raise ValueError(f"chunk [{off}, {upto}) out of range "
-                                 f"for prompt length {s.seq_len}")
-            final = upto == s.seq_len
-            cover = min(s.seq_len + 1, s.total_len) if final else upto
-            fresh = btm.ensure(req, cover)
-            if fresh:
-                self._reserved[req] = max(
-                    self._reserved[req] - len(fresh), 0)
-        # ---- segment descriptors: admissions first, then chunks -------
-        # (suffix tokens, position offset, prefix pool indices)
-        bs = self.block_size
-        suffixes: List[List[int]] = []
-        offsets: List[int] = []
-        pre_fidx: List[np.ndarray] = []
-        pre_seg: List[np.ndarray] = []
-        pre_pos: List[np.ndarray] = []
+                    f"sessions {over} exceed the emission buffer "
+                    f"(max_new_tokens > cap_new={self.cap_new}); raise "
+                    f"cap_new or lower the budget")
+            dup = [s.req_id for s in admissions
+                   if eng.kv_slab.has_region(s.req_id)]
+            if dup:
+                raise ValueError(f"req_ids {dup} already hold KV regions "
+                                 "(duplicate in-flight submission?)")
+            if admissions:
+                need = eng.ladder.seq_bucket(
+                    max(s.total_len for s in admissions))
+                self._ensure_state(need)
+            taken = set(self._chunk_slots.values())
+            slots = [i for i, s in enumerate(self.sessions)
+                     if s is None and i not in taken][:len(admissions)]
+            assert len(slots) == len(admissions), "admitted beyond free slots"
+            matches: Optional[List[PrefixMatch]] = None
+            if self.prefix_cache is not None and admissions:
+                matches = [self.prefix_cache.match(list(s.prompt))
+                           for s in admissions]
+            btm = self.block_table
+            if admissions:
+                want = 0
+                for i, s in enumerate(admissions):
+                    covered = len(matches[i].full_blocks) if matches else 0
+                    want += btm.blocks_needed(s.total_len) - covered
+                deficit = want + sum(self._reserved.values()) - \
+                    btm.free_blocks
+                if deficit > 0 and self.prefix_cache is not None:
+                    deficit -= self.prefix_cache.evict(deficit)
+                if deficit > 0:
+                    if matches:
+                        for m in matches:
+                            self.prefix_cache.release(m)
+                    raise ValueError(
+                        f"packed prefill needs {want} fresh KV blocks beyond "
+                        f"reservations, pool has {btm.free_blocks} free — "
+                        "the admission planner should have vetoed this pack")
+            # ---- chunk validation + block ensure (reserved at admission,
+            # so ensure cannot exhaust the pool) -----------------------------
+            for s, upto in chunks:
+                req = s.req_id
+                off = s.prefilled_tokens
+                if req not in self._chunk_slots:
+                    raise ValueError(f"session {req} has no chunked prefill "
+                                     "in flight")
+                if not off < upto <= s.seq_len:
+                    raise ValueError(f"chunk [{off}, {upto}) out of range "
+                                     f"for prompt length {s.seq_len}")
+                final = upto == s.seq_len
+                cover = min(s.seq_len + 1, s.total_len) if final else upto
+                fresh = btm.ensure(req, cover)
+                if fresh:
+                    self._reserved[req] = max(
+                        self._reserved[req] - len(fresh), 0)
+            # ---- segment descriptors: admissions first, then chunks -------
+            # (suffix tokens, position offset, prefix pool indices)
+            bs = self.block_size
+            suffixes: List[List[int]] = []
+            offsets: List[int] = []
+            pre_fidx: List[np.ndarray] = []
+            pre_seg: List[np.ndarray] = []
+            pre_pos: List[np.ndarray] = []
 
-        def add_prefix(seg: int, blocks: List[int], length: int) -> None:
-            pos = np.arange(length)
-            ids = np.asarray(blocks, np.int32)
-            pre_fidx.append(ids[pos // bs] * bs + pos % bs)
-            pre_seg.append(np.full((length,), seg, np.int32))
-            pre_pos.append(pos.astype(np.int32))
+            def add_prefix(seg: int, blocks: List[int], length: int) -> None:
+                pos = np.arange(length)
+                ids = np.asarray(blocks, np.int32)
+                pre_fidx.append(ids[pos // bs] * bs + pos % bs)
+                pre_seg.append(np.full((length,), seg, np.int32))
+                pre_pos.append(pos.astype(np.int32))
 
-        for i, s in enumerate(admissions):
-            cached = matches[i].cached_tokens if matches else 0
-            suffixes.append(list(s.prompt)[cached:])
-            offsets.append(cached)
-            if cached:
-                blocks = list(matches[i].full_blocks)
-                if matches[i].tail_block is not None:
-                    blocks.append(matches[i].tail_block)
-                add_prefix(i, blocks, cached)
-        for j, (s, upto) in enumerate(chunks):
-            off = s.prefilled_tokens
-            suffixes.append(list(s.prompt)[off:upto])
-            offsets.append(off)
-            if off:
-                add_prefix(len(admissions) + j,
-                           list(btm.block_table(s.req_id)), off)
-        # ---- gather every segment's prefix KV in one pool read --------
-        st = self.state
-        k_pool, v_pool = st.cache["k"], st.cache["v"]
-        pool_blocks = k_pool.shape[1]
-        flat_shape = (k_pool.shape[0], pool_blocks * bs) + \
-            k_pool.shape[3:]
-        if pre_fidx:
-            gidx = jnp.asarray(np.concatenate(pre_fidx))
-            prefix_k = k_pool.reshape(flat_shape)[:, gidx]
-            prefix_v = v_pool.reshape(flat_shape)[:, gidx]
-            prefix_seg = jnp.asarray(np.concatenate(pre_seg))
-            prefix_pos = jnp.asarray(np.concatenate(pre_pos))
-        else:
-            prefix_k = jnp.zeros(
-                (k_pool.shape[0], 0) + k_pool.shape[3:], k_pool.dtype)
-            prefix_v = prefix_k
-            prefix_seg = jnp.zeros((0,), jnp.int32)
-            prefix_pos = jnp.zeros((0,), jnp.int32)
+            for i, s in enumerate(admissions):
+                cached = matches[i].cached_tokens if matches else 0
+                suffixes.append(list(s.prompt)[cached:])
+                offsets.append(cached)
+                if cached:
+                    blocks = list(matches[i].full_blocks)
+                    if matches[i].tail_block is not None:
+                        blocks.append(matches[i].tail_block)
+                    add_prefix(i, blocks, cached)
+            for j, (s, upto) in enumerate(chunks):
+                off = s.prefilled_tokens
+                suffixes.append(list(s.prompt)[off:upto])
+                offsets.append(off)
+                if off:
+                    add_prefix(len(admissions) + j,
+                               list(btm.block_table(s.req_id)), off)
+            # ---- gather every segment's prefix KV in one pool read --------
+            st = self.state
+            k_pool, v_pool = st.cache["k"], st.cache["v"]
+            pool_blocks = k_pool.shape[1]
+            flat_shape = (k_pool.shape[0], pool_blocks * bs) + \
+                k_pool.shape[3:]
+            if pre_fidx:
+                gidx = jnp.asarray(np.concatenate(pre_fidx))
+                prefix_k = k_pool.reshape(flat_shape)[:, gidx]
+                prefix_v = v_pool.reshape(flat_shape)[:, gidx]
+                prefix_seg = jnp.asarray(np.concatenate(pre_seg))
+                prefix_pos = jnp.asarray(np.concatenate(pre_pos))
+            else:
+                prefix_k = jnp.zeros(
+                    (k_pool.shape[0], 0) + k_pool.shape[3:], k_pool.dtype)
+                prefix_v = prefix_k
+                prefix_seg = jnp.zeros((0,), jnp.int32)
+                prefix_pos = jnp.zeros((0,), jnp.int32)
         try:
             # ---- THE dispatch -----------------------------------------
-            logits, parts = eng.prefill_packed_flat(
-                suffixes, offsets, prefix_k, prefix_v, prefix_seg,
-                prefix_pos)
-            # ---- allocate admission tables (prefix refs adopted, tail
-            # copy-on-write) and collect every segment's scatter target -
-            cache = dict(st.cache)
-            k_pool, v_pool = cache["k"], cache["v"]
-            tables = cache["block_tables"]
-            tgt: List[np.ndarray] = []
-            pack_ledger: Dict[int, List[int]] = {}
-            written: set = set()
-            seg_bids: List[List[int]] = []
-            for i, s in enumerate(admissions):
-                m = matches[i] if matches is not None else None
-                cached = 0
-                prefix_blocks: List[int] = []
-                if m is not None:
-                    m.consumed = True   # holds transfer to the table
-                    cached = m.cached_tokens
-                    prefix_blocks = list(m.full_blocks)
-                    if m.tail_block is not None:
-                        try:
-                            cow = btm.take(1)[0]
-                        except BlockExhausted:
-                            for b in prefix_blocks:
-                                btm.unref(b)
+            with span_of(self.trace, "engine.dispatch"):
+                logits, parts = eng.prefill_packed_flat(
+                    suffixes, offsets, prefix_k, prefix_v, prefix_seg,
+                    prefix_pos)
+            with span_of(self.trace, "engine.splice"):
+                # ---- allocate admission tables (prefix refs adopted, tail
+                # copy-on-write) and collect every segment's scatter target -
+                cache = dict(st.cache)
+                k_pool, v_pool = cache["k"], cache["v"]
+                tables = cache["block_tables"]
+                tgt: List[np.ndarray] = []
+                pack_ledger: Dict[int, List[int]] = {}
+                written: set = set()
+                seg_bids: List[List[int]] = []
+                for i, s in enumerate(admissions):
+                    m = matches[i] if matches is not None else None
+                    cached = 0
+                    prefix_blocks: List[int] = []
+                    if m is not None:
+                        m.consumed = True   # holds transfer to the table
+                        cached = m.cached_tokens
+                        prefix_blocks = list(m.full_blocks)
+                        if m.tail_block is not None:
+                            try:
+                                cow = btm.take(1)[0]
+                            except BlockExhausted:
+                                for b in prefix_blocks:
+                                    btm.unref(b)
+                                btm.unref(m.tail_block)
+                                raise
+                            k_pool = k_pool.at[:, cow].set(
+                                k_pool[:, m.tail_block])
+                            v_pool = v_pool.at[:, cow].set(
+                                v_pool[:, m.tail_block])
                             btm.unref(m.tail_block)
-                            raise
-                        k_pool = k_pool.at[:, cow].set(
-                            k_pool[:, m.tail_block])
-                        v_pool = v_pool.at[:, cow].set(
-                            v_pool[:, m.tail_block])
-                        btm.unref(m.tail_block)
-                        prefix_blocks.append(cow)
-                        self.cow_blocks += 1
-                alloc_tokens = min(s.seq_len + 1, s.total_len)
-                try:
-                    bids = btm.allocate(s.req_id, alloc_tokens,
-                                        prefix_blocks=prefix_blocks)
-                except BlockExhausted:
-                    for b in prefix_blocks:
-                        btm.unref(b)
-                    raise
-                self._reserved[s.req_id] = max(
-                    btm.blocks_needed(s.total_len) - len(bids), 0)
-                seg_bids.append(bids)
-            for s, upto in chunks:
-                seg_bids.append(btm.block_table(s.req_id))
-            spans = [(s, off, s.seq_len)
-                     for s, off in zip(admissions, offsets)] + \
-                    [(s, s.prefilled_tokens, upto) for s, upto in chunks]
-            for (s, off, end), bids in zip(spans, seg_bids):
-                seg_blocks = bids[off // bs:(end - 1) // bs + 1]
-                sanitizer.check_write(btm, s.req_id, seg_blocks)
-                overlap = [b for b in seg_blocks if b in written]
-                if overlap:
-                    raise sanitizer.SanitizerError(
-                        f"pack segments overlap on blocks {overlap} "
-                        f"(session {s.req_id}) — cross-request KV "
-                        "corruption")
-                written.update(seg_blocks)
-                pack_ledger[s.req_id] = list(seg_blocks)
-                pos = np.arange(off, end)
-                tgt.append(np.asarray(bids, np.int32)[pos // bs] * bs +
-                           pos % bs)
-            # ---- ONE scatter: the flat pack lines up with the
-            # concatenated per-segment targets ---------------------------
-            flat = sum(len(s) for s in suffixes)
-            fidx = jnp.asarray(np.concatenate(tgt))
-            k_pool = k_pool.reshape(flat_shape).at[:, fidx].set(
-                parts["k"][:, :flat]).reshape(k_pool.shape)
-            v_pool = v_pool.reshape(flat_shape).at[:, fidx].set(
-                parts["v"][:, :flat]).reshape(v_pool.shape)
-            cache["k"], cache["v"] = k_pool, v_pool
-            # ---- splice decode rows: admissions + final chunks --------
-            splicers: List[Tuple[int, int, Session]] = []
-            for i, (slot, s) in enumerate(zip(slots, admissions)):
-                splicers.append((i, slot, s))
-            for j, (s, upto) in enumerate(chunks):
-                if upto == s.seq_len:
-                    splicers.append((len(admissions) + j,
-                                     self._chunk_slots[s.req_id], s))
-            if splicers:
-                ns = len(splicers)
-                batch_b = eng.ladder.batch_bucket(ns)
-                sel = jnp.asarray(np.array(
-                    [seg for seg, _, _ in splicers] +
-                    [0] * (batch_b - ns), np.int32))
-                ctl_cache = {
-                    "len": jnp.asarray(np.array(
-                        [s.seq_len for _, _, s in splicers] +
-                        [1] * (batch_b - ns), np.int32)),
-                    "pos_offset": jnp.zeros((batch_b,), jnp.int32),
-                }
-                rows = eng._finish_gen_state(
-                    logits[sel], ctl_cache, ns, batch_b,
-                    budgets=[s.max_new_tokens for _, _, s in splicers],
-                    eos_ids=[s.eos_id for _, _, s in splicers],
-                    cap=self.cap_new,
-                    sampling=[s.params for _, _, s in splicers])
-                for (seg, slot, s) in splicers:
-                    row = np.zeros((self.max_blocks,), np.int32)
-                    bids = seg_bids[seg]
-                    row[:len(bids)] = bids
-                    tables = tables.at[slot].set(jnp.asarray(row))
-                cache["block_tables"] = tables
-                idx = jnp.asarray(np.array(
-                    [slot for _, slot, _ in splicers], np.int32))
-                for key in _BATCH_AXIS0:
-                    cache[key] = cache[key].at[idx].set(
-                        _rows(rows.cache[key], key, ns))
-                self.state = self._spliced(cache, rows, idx, ns)
-            else:
-                self.state = replace(st, cache=cache)
+                            prefix_blocks.append(cow)
+                            self.cow_blocks += 1
+                    alloc_tokens = min(s.seq_len + 1, s.total_len)
+                    try:
+                        bids = btm.allocate(s.req_id, alloc_tokens,
+                                            prefix_blocks=prefix_blocks)
+                    except BlockExhausted:
+                        for b in prefix_blocks:
+                            btm.unref(b)
+                        raise
+                    self._reserved[s.req_id] = max(
+                        btm.blocks_needed(s.total_len) - len(bids), 0)
+                    seg_bids.append(bids)
+                for s, upto in chunks:
+                    seg_bids.append(btm.block_table(s.req_id))
+                spans = [(s, off, s.seq_len)
+                         for s, off in zip(admissions, offsets)] + \
+                        [(s, s.prefilled_tokens, upto) for s, upto in chunks]
+                for (s, off, end), bids in zip(spans, seg_bids):
+                    seg_blocks = bids[off // bs:(end - 1) // bs + 1]
+                    sanitizer.check_write(btm, s.req_id, seg_blocks)
+                    overlap = [b for b in seg_blocks if b in written]
+                    if overlap:
+                        raise sanitizer.SanitizerError(
+                            f"pack segments overlap on blocks {overlap} "
+                            f"(session {s.req_id}) — cross-request KV "
+                            "corruption")
+                    written.update(seg_blocks)
+                    pack_ledger[s.req_id] = list(seg_blocks)
+                    pos = np.arange(off, end)
+                    tgt.append(np.asarray(bids, np.int32)[pos // bs] * bs +
+                               pos % bs)
+                # ---- ONE scatter: the flat pack lines up with the
+                # concatenated per-segment targets ---------------------------
+                flat = sum(len(s) for s in suffixes)
+                fidx = jnp.asarray(np.concatenate(tgt))
+                k_pool = k_pool.reshape(flat_shape).at[:, fidx].set(
+                    parts["k"][:, :flat]).reshape(k_pool.shape)
+                v_pool = v_pool.reshape(flat_shape).at[:, fidx].set(
+                    parts["v"][:, :flat]).reshape(v_pool.shape)
+                cache["k"], cache["v"] = k_pool, v_pool
+                # ---- splice decode rows: admissions + final chunks --------
+                splicers: List[Tuple[int, int, Session]] = []
+                for i, (slot, s) in enumerate(zip(slots, admissions)):
+                    splicers.append((i, slot, s))
+                for j, (s, upto) in enumerate(chunks):
+                    if upto == s.seq_len:
+                        splicers.append((len(admissions) + j,
+                                         self._chunk_slots[s.req_id], s))
+                if splicers:
+                    ns = len(splicers)
+                    batch_b = eng.ladder.batch_bucket(ns)
+                    sel = jnp.asarray(np.array(
+                        [seg for seg, _, _ in splicers] +
+                        [0] * (batch_b - ns), np.int32))
+                    ctl_cache = {
+                        "len": jnp.asarray(np.array(
+                            [s.seq_len for _, _, s in splicers] +
+                            [1] * (batch_b - ns), np.int32)),
+                        "pos_offset": jnp.zeros((batch_b,), jnp.int32),
+                    }
+                    with span_of(self.trace, "engine.sample"):
+                        rows = eng._finish_gen_state(
+                            logits[sel], ctl_cache, ns, batch_b,
+                            budgets=[s.max_new_tokens for _, _, s in splicers],
+                            eos_ids=[s.eos_id for _, _, s in splicers],
+                            cap=self.cap_new,
+                            sampling=[s.params for _, _, s in splicers])
+                    for (seg, slot, s) in splicers:
+                        row = np.zeros((self.max_blocks,), np.int32)
+                        bids = seg_bids[seg]
+                        row[:len(bids)] = bids
+                        tables = tables.at[slot].set(jnp.asarray(row))
+                    cache["block_tables"] = tables
+                    idx = jnp.asarray(np.array(
+                        [slot for _, slot, _ in splicers], np.int32))
+                    for key in _BATCH_AXIS0:
+                        cache[key] = cache[key].at[idx].set(
+                            _rows(rows.cache[key], key, ns))
+                    self.state = self._spliced(cache, rows, idx, ns)
+                else:
+                    self.state = replace(st, cache=cache)
         except Exception:
             # mirror prefill_batch's sweep: free admission tables and
             # holds, neutralize any slot whose row state may have been
@@ -1765,11 +1795,12 @@ class ContinuousEngine(PipelineBackend):
         if fresh:
             self._reserved[req] = max(self._reserved[req] - len(fresh), 0)
         pk, pv = self._gather_own_prefix(req, off)
-        rows = eng.prefill_suffix_batch(
-            [list(session.prompt)[:upto]], prefix_k=pk, prefix_v=pv,
-            prefix_len=off, max_new_tokens=[session.max_new_tokens],
-            eos_id=[session.eos_id], cap_new=self.cap_new,
-            sampling=[session.params])
+        with span_of(self.trace, "engine.dispatch"):
+            rows = eng.prefill_suffix_batch(
+                [list(session.prompt)[:upto]], prefix_k=pk, prefix_v=pv,
+                prefix_len=off, max_new_tokens=[session.max_new_tokens],
+                eos_id=[session.eos_id], cap_new=self.cap_new,
+                sampling=[session.params])
         bids = btm.block_table(req)
         bs = self.block_size
         # sanitizer: the chunk scatters into exactly these blocks
@@ -1846,10 +1877,11 @@ class ContinuousEngine(PipelineBackend):
             raise ValueError(f"session {session.req_id} holds no decode "
                              "slot")
         st = self.state
-        # turbolint: allow-sync(cancellation reads the partial result once)
-        counts = int(np.asarray(st.counts[slot]))
-        # turbolint: allow-sync(cancellation reads the partial result once)
-        emitted = np.asarray(st.emitted[slot])
+        with span_of(self.trace, "engine.stream"):
+            # turbolint: allow-sync(cancellation reads the partial result once)
+            counts = int(np.asarray(st.counts[slot]))
+            # turbolint: allow-sync(cancellation reads the partial result once)
+            emitted = np.asarray(st.emitted[slot])
         session.generated = [int(x) for x in emitted[:counts]]
         self.engine.kv_slab.free(session.req_id)
         self.engine.kv_slab.gc()
@@ -2193,46 +2225,50 @@ class ContinuousEngine(PipelineBackend):
             self.state = replace(st, cache=cache)
 
     def _sync(self) -> None:
-        """Flush: read the (tiny) stop flags; only when an occupied slot
-        newly finished is the token buffer transferred — the hot decode
-        loop moves no per-token data to the host."""
+        """Flush: read the (tiny) stop flags — the read that waits for
+        the tick's programs (span ``engine.wait``); only when an occupied
+        slot newly finished is the token buffer transferred and the row
+        released (``engine.stream``) — the hot decode loop moves no
+        per-token data to the host."""
         self._since_sync = 0
         st = self.state
-        done = np.asarray(st.done)    # turbolint: allow-sync(stop-flag flush)
+        with span_of(self.trace, "engine.wait"):
+            done = np.asarray(st.done)    # turbolint: allow-sync(stop-flag flush)
         if not any(done[slot] for slot, s in enumerate(self.sessions)
                    if s is not None):
             return
-        # turbolint: allow-sync(finished rows only — the once-per-generation flush)
-        counts = np.asarray(st.counts)
-        # turbolint: allow-sync(finished rows only — the once-per-generation flush)
-        emitted = np.asarray(st.emitted)
-        now = self.clock()
-        freed_slots: List[int] = []
-        for slot, s in enumerate(self.sessions):
-            if s is None or not done[slot]:
-                continue
-            s.generated = [int(x) for x in emitted[slot, :counts[slot]]]
-            s.result = list(s.prompt or []) + s.generated
-            s.finish(now)
-            self.engine.kv_slab.free(s.req_id)
-            if self.block_table is not None:
-                self.block_table.free(s.req_id)
-                self._reserved.pop(s.req_id, None)
-            self._last_pack.pop(s.req_id, None)
-            self.sessions[slot] = None
-            self._slot_len[slot] = 0
-            freed_slots.append(slot)
-        if freed_slots:
-            self.engine.kv_slab.gc()
-            if self.block_table is not None:
-                # point freed rows at the trash block: their device rows
-                # keep writing at a frozen position until re-admission,
-                # and the freed physical blocks may be re-assigned
-                st = self.state
-                cache = dict(st.cache)
-                cache["block_tables"] = cache["block_tables"].at[
-                    jnp.asarray(np.array(freed_slots, np.int32))].set(0)
-                self.state = replace(st, cache=cache)
+        with span_of(self.trace, "engine.stream"):
+            # turbolint: allow-sync(finished rows only — the once-per-generation flush)
+            counts = np.asarray(st.counts)
+            # turbolint: allow-sync(finished rows only — the once-per-generation flush)
+            emitted = np.asarray(st.emitted)
+            now = self.clock()
+            freed_slots: List[int] = []
+            for slot, s in enumerate(self.sessions):
+                if s is None or not done[slot]:
+                    continue
+                s.generated = [int(x) for x in emitted[slot, :counts[slot]]]
+                s.result = list(s.prompt or []) + s.generated
+                s.finish(now)
+                self.engine.kv_slab.free(s.req_id)
+                if self.block_table is not None:
+                    self.block_table.free(s.req_id)
+                    self._reserved.pop(s.req_id, None)
+                self._last_pack.pop(s.req_id, None)
+                self.sessions[slot] = None
+                self._slot_len[slot] = 0
+                freed_slots.append(slot)
+            if freed_slots:
+                self.engine.kv_slab.gc()
+                if self.block_table is not None:
+                    # point freed rows at the trash block: their device rows
+                    # keep writing at a frozen position until re-admission,
+                    # and the freed physical blocks may be re-assigned
+                    st = self.state
+                    cache = dict(st.cache)
+                    cache["block_tables"] = cache["block_tables"].at[
+                        jnp.asarray(np.array(freed_slots, np.int32))].set(0)
+                    self.state = replace(st, cache=cache)
 
     @property
     def live_tokens(self) -> int:
