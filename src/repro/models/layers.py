@@ -5,8 +5,9 @@ All functions are pure; parameters are plain dicts of jnp arrays so they
 stack cleanly along a leading layer dim for ``lax.scan``. Activation
 sharding uses logical-axis annotations (`repro.distributed.constrain`).
 Each layer runs under a ``jax.named_scope`` (``norm``, ``qkv``,
-``attention``, ``expand_kv``, ``out_proj``, ``ffn``, ``embed``,
-``lm_head``), so every device operation in a profile names its layer.
+``attention``, ``expand_kv``, ``gqa_grouped``, ``out_proj``, ``ffn``,
+``embed``, ``lm_head``), so every device operation in a profile names its
+layer.
 """
 from __future__ import annotations
 
@@ -209,7 +210,9 @@ def expand_kv(x: jax.Array, groups: int,
     expanded H dim shards evenly and each device materializes only its own
     slice of the (broadcast) expansion. ``constrain_heads=False`` leaves
     the layout to propagation (decode: the cache may be sequence-sharded
-    and must not be reshuffled onto heads every step)."""
+    and must not be reshuffled onto heads every step). Prefill and decode
+    under sharding rules use it; one-device decode contracts the grouped
+    heads instead (``attention_decode``)."""
     if groups == 1:
         return x
     b, s, kv, dh = x.shape
@@ -405,18 +408,25 @@ def attention_decode(cfg: ModelConfig, q, k_cache, v_cache, cache_len
                      ) -> jax.Array:
     """Decode attention: q (B,1,H,dh) against cache (B,S,KV,dh).
 
-    ``cache_len`` (B,) masks positions >= current length. The kv sequence
-    dim may be sharded over 'model' (context parallelism) — GSPMD inserts
-    the partial softmax-max/sum collectives automatically.
+    ``cache_len`` (B,) masks positions >= current length. Without sharding
+    rules (one device) each KV head's keys and values are contracted with
+    its G = H/KV query heads in place (``gqa_grouped``). Under rules the
+    cache is expanded to H heads first (``expand_kv``), so heads shard
+    even when KV < tp_size; the kv sequence dim may be sharded over
+    'model' (context parallelism) — GSPMD inserts the partial
+    softmax-max/sum collectives automatically.
     """
     b, _, h, dh = q.shape
     kvh = k_cache.shape[2]
+    scale = 1.0 / math.sqrt(dh)
+    valid = jnp.arange(k_cache.shape[1])[None, :] < cache_len[:, None]
+    rules = current_rules()
+    if rules is None:
+        return _decode_grouped(q, k_cache, v_cache, valid, scale)
     k_full = expand_kv(k_cache, h // kvh, constrain_heads=False)
     v_full = expand_kv(v_cache, h // kvh, constrain_heads=False)
-    scale = 1.0 / math.sqrt(dh)
     q3 = q[:, 0]
-    rules = current_rules()
-    if rules is not None and rules.rules.get("kv_dh_shard"):
+    if rules.rules.get("kv_dh_shard"):
         # head-dim-sharded KV cache: keep q on the SAME dh sharding so the
         # q.k contraction stays a local partial dot + psum of the small
         # (B,H,S) scores — instead of all-gathering the 1GB-per-layer
@@ -424,15 +434,29 @@ def attention_decode(cfg: ModelConfig, q, k_cache, v_cache, cache_len
         q3 = constrain(q3, "batch", None, "act_dh")
     s = jnp.einsum("bhd,bshd->bhs", q3, k_full) * scale
     s = s.astype(jnp.float32)
-    valid = jnp.arange(k_cache.shape[1])[None, :] < cache_len[:, None]
     s = jnp.where(valid[:, None, :], s, -jnp.inf)
     w = jax.nn.softmax(s, axis=-1).astype(q.dtype)
     out = jnp.einsum("bhs,bshd->bhd", w, v_full)
-    if rules is not None and rules.rules.get("kv_dh_shard"):
+    if rules.rules.get("kv_dh_shard"):
         # keep the PV product dh-sharded too (V stays local); the output
         # projection contracts (h, dh) with a psum instead of gathering V
         out = constrain(out, "batch", None, "act_dh")
     return out[:, None]
+
+
+@jax.named_scope("gqa_grouped")
+def _decode_grouped(q, k_cache, v_cache, valid, scale) -> jax.Array:
+    """``attention_decode`` without the GQA copy: query head h = kv*G + g
+    (``expand_kv``'s order) reads its KV head's cache directly."""
+    b, _, h, dh = q.shape
+    kvh = k_cache.shape[2]
+    qg = q[:, 0].reshape(b, kvh, h // kvh, dh)
+    s = jnp.einsum("bkgd,bskd->bkgs", qg, k_cache) * scale
+    s = s.astype(jnp.float32)
+    s = jnp.where(valid[:, None, None, :], s, -jnp.inf)
+    w = jax.nn.softmax(s, axis=-1).astype(q.dtype)
+    out = jnp.einsum("bkgs,bskd->bkgd", w, v_cache)
+    return out.reshape(b, 1, h, dh)
 
 
 @jax.named_scope("out_proj")
