@@ -104,6 +104,47 @@ class CompileNames(logging.Handler):
             logger.propagate = True
 
 
+class Programs:
+    """The executables JAX compiles or reads from its persistent cache
+    between ``start`` and ``stop`` (its compile path is wrapped for that
+    span), kept to read each one's optimized HLO: the TPU's profiler
+    trace names an op (``%fusion.200 = bf16[...] fusion(...)``) and its
+    program (``jit_tick(<id>)``), but not the scope it was traced under."""
+
+    def __init__(self) -> None:
+        self.made: List[Tuple[str, object]] = []    # (module name, exe)
+        self._orig: Optional[Callable] = None
+
+    def start(self) -> None:
+        from jax._src import compiler
+        from jax._src.lib.mlir import ir
+        orig = self._orig = compiler.compile_or_get_cached
+
+        def capture(backend, computation, *args, **kw):
+            exe = orig(backend, computation, *args, **kw)
+            name = ir.StringAttr(
+                computation.operation.attributes["sym_name"]).value
+            self.made.append((name, exe))
+            return exe
+        compiler.compile_or_get_cached = capture
+
+    def stop(self) -> None:
+        if self._orig is not None:
+            from jax._src import compiler
+            compiler.compile_or_get_cached = self._orig
+            self._orig = None
+
+    def hlo_texts(self, name: str) -> List[str]:
+        """Optimized HLO text, with metadata, of every executable of the
+        program ``name`` (``jit_tick``: one per compiled variant)."""
+        from jax._src.lib import _jax
+        opts = _jax.HloPrintOptions()
+        opts.print_metadata = True
+        opts.print_large_constants = False
+        return [m.to_string(opts) for n, exe in self.made if n == name
+                for m in exe.hlo_modules()]
+
+
 def build_client(cell: dict, cfg, params, *, trace: bool,
                  clock: Callable[[], float] = CLOCK):
     """The serving stack with the cell's settings (``serving`` and

@@ -1,15 +1,14 @@
-"""Model step: operations the decode ticks of the traced span need (from
-true context lengths, ``bench/roofline.py``) over their summed host time
-times the chip's peak, in %."""
-from bench import roofline
+"""Model step: operations the decode ticks of the traced span need (the
+configuration's ``decode_tick`` count, from true context lengths) over
+their summed host time times the chip's peak, in %."""
 
 
 def read(record):
-    dm, peak = record["dims"], record["peaks"]["flops_per_s"]
+    count, peak = record["counts"], record["peaks"]["flops_per_s"]
     ops = secs = 0.0
     for p in record["pumps"]:
         if p.decoded and not p.segments:
-            ops += roofline.decode_tick(dm, p.rows, p.context)[0]
+            ops += count("decode_tick", p)[0]
             secs += p.t1 - p.t0
     if not secs:
         return None

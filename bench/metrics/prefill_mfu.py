@@ -1,15 +1,14 @@
-"""Model step: operations the prefill ticks of the traced span need (true
-fresh and cached lengths, ``bench/roofline.py``) over their summed host
-time times the chip's peak, in %."""
-from bench import roofline
+"""Model step: operations the prefill ticks of the traced span need (the
+configuration's ``prefill`` count, from true fresh and cached lengths)
+over their summed host time times the chip's peak, in %."""
 
 
 def read(record):
-    dm, peak = record["dims"], record["peaks"]["flops_per_s"]
+    count, peak = record["counts"], record["peaks"]["flops_per_s"]
     ops = secs = 0.0
     for p in record["pumps"]:
         if p.segments and not p.decoded:
-            ops += roofline.prefill(dm, p.segments)[0]
+            ops += sum(o for o, _ in count("prefill", p))
             secs += p.t1 - p.t0
     if not secs:
         return None

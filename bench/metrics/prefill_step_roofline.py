@@ -1,8 +1,8 @@
 """Kernels: the packed-prefill program's share of its roofline in the
-traced span: summed least time of the prefill dispatches
-(``bench/roofline.py``, one dispatch per admitted prompt) over the
+traced span: summed least time of the prefill dispatches (the
+configuration's ``prefill`` count, one entry per dispatch) over the
 device time of the ``jit_pf`` program in the profiler trace, in %."""
-from bench import roofline
+from bench.roofline import least_time
 
 PROGRAM = "jit_pf"
 
@@ -13,9 +13,10 @@ def read(record):
         return None
     secs = sum(v for k, v in dev["programs"].items()
                if k.split("(")[0].split(".")[0] == PROGRAM)
-    need = sum(roofline.least_time(*roofline.prefill(
-        record["dims"], [seg]), record["peaks"])
-        for p in record["pumps"] for seg in p.segments)
+    count = record["counts"]
+    need = sum(least_time(ops, nbytes, record["peaks"])
+               for p in record["pumps"]
+               for ops, nbytes in count("prefill", p))
     if not secs or not need:
         return None
     return 100.0 * need / secs

@@ -28,6 +28,10 @@ one layer of the same values again for the reference.
 ``lowp="fp8"`` is the control: every matrix product takes its inputs
 rounded to float8 e4m3 (per-tensor scale for weights, per-row for
 activations), the precision below the configuration's bfloat16.
+
+The counts the per-layer readers price a run by are the configuration's
+too: ``count_<name>(dm, pump, events)`` for each name in
+``bench.spec.COUNTS``, here ``bench/roofline.py``'s dense GQA counts.
 """
 from __future__ import annotations
 
@@ -40,6 +44,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 from jax import lax
+
+from bench import roofline
 
 HIGHEST = lax.Precision.HIGHEST
 FP8_MAX = 448.0
@@ -68,6 +74,27 @@ def dims(config: dict) -> dict:
 
 def _key(config: dict) -> tuple:
     return tuple(sorted(dims(config).items()))
+
+
+# -- counts ----------------------------------------------------------------
+# (ops, bytes) of what one harness pump ran, from its true lengths;
+# ``events`` are the program's trace events that began inside the pump
+# (unused by dense counts, which depend on lengths alone).
+def count_decode_tick(dm: dict, pump, events) -> Tuple[float, float]:
+    """The pump's decode tick: every row's layers, head and attention."""
+    return roofline.decode_tick(dm, pump.rows, pump.context)
+
+
+def count_prefill(dm: dict, pump, events) -> List[Tuple[float, float]]:
+    """One entry per prefill dispatch of the pump: each admitted prompt
+    is a dispatch of its own (one prompt per packed prefill)."""
+    return [roofline.prefill(dm, [seg]) for seg in pump.segments]
+
+
+def count_decode_attention(dm: dict, pump, events) -> Tuple[float, float]:
+    """The attention of the pump's decode tick, at the rows' true
+    lengths."""
+    return roofline.decode_attention(dm, pump.rows, pump.context)
 
 
 # -- weights ---------------------------------------------------------------

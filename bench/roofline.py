@@ -1,6 +1,15 @@
 """Operations and bytes the serving programs need, from true lengths.
 
-Counts are for dense GQA decoders, from the configuration file (see
+These are the dense GQA counts, and ``least_time``, which every count
+shares. A configuration owns its counts: its plain reference
+(``bench/references/<reference>.py``) exports ``count_<name>`` for each
+name in ``bench.spec.COUNTS``, and the per-layer readers call those
+through the record, never this module's counts. The dense reference
+(``bench/references/dense_gqa.py``) takes them from here; an
+architecture these counts do not price (sparse experts, window layers)
+brings a reference of its own with its own counts, as new files.
+
+Counts are from the configuration file (see
 ``bench/references/dense_gqa.dims``), never from padded shapes:
 
 - a matrix product of a token with an ``m x n`` weight is ``2 m n`` ops;
@@ -75,6 +84,18 @@ def prefill(dm: dict, segments: Iterable[Tuple[int, int]]
         ops += attention_ops(dm, fresh, keys)
         nbytes += kvb * (fresh + cached) + fresh * dm["d"] * 2
     return ops, nbytes
+
+
+def decode_attention(dm: dict, rows: int, context: int
+                     ) -> Tuple[float, float]:
+    """(ops, bytes) of the attention of one decode tick over ``rows``
+    sequences whose KV contexts sum to ``context`` tokens: each row's
+    query reads the K and V of its context and of the token it adds,
+    once, and each layer reads q and writes the output of every row."""
+    keys = context + rows
+    qo = 2 * rows * dm["heads"] * dm["dh"] * dm["layers"] * 2
+    return attention_ops(dm, rows, keys), \
+        float(kv_bytes_per_token(dm) * keys + qo)
 
 
 def least_time(ops: float, nbytes: float, peaks: dict) -> float:

@@ -65,10 +65,11 @@ def log_stalls(driver, win: harness.Window, gcs: GcPauses) -> None:
         f"{max((g[1] for g in full), default=0.0) * 1e3:.1f} ms")
 
 
-def per_layer_record(cell: spec.Cell, dm: dict, peaks: dict, driver,
+def per_layer_record(cell: spec.Cell, ref, dm: dict, peaks: dict, driver,
                      win: harness.Window, counters: dict,
                      device: Optional[dict], memory: dict) -> dict:
-    """What the per-layer readers read, cut to the traced span."""
+    """What the per-layer readers read, cut to the traced span; ``counts``
+    prices a pump by the configuration's reference ``ref``."""
     lo, hi = win.traced if win.traced else (win.opened, win.closed)
     ticks = [e for e in driver.client.trace_events()
              if "dur" in e and lo <= e["ts"] < hi]
@@ -76,7 +77,7 @@ def per_layer_record(cell: spec.Cell, dm: dict, peaks: dict, driver,
                 if r.req.idx >= 0 and r.session.prefill_time is not None
                 and lo <= r.session.prefill_time < hi]
     return {
-        "dims": dm, "peaks": peaks,
+        "dims": dm, "peaks": peaks, "counts": spec.Counts(ref, dm, ticks),
         "block_size": cell.cell["serving"].get("block_size", 16),
         "span": (lo, hi),
         "pumps": [p for p in driver.pumps if lo <= p.t0 < hi],
@@ -90,6 +91,16 @@ def per_layer_record(cell: spec.Cell, dm: dict, peaks: dict, driver,
     }
 
 
+def scope_paths(programs: harness.Programs, trace: dict, lo: int,
+                hi: int) -> Dict[str, Dict[str, str]]:
+    """``{program: {op: name path}}`` (``trace_reduce.hlo_paths``) for the
+    programs that ran in [lo, hi), from their optimized HLO."""
+    names = {trace_reduce.program_name(n)
+             for n, *_ in trace_reduce.clip(trace["modules"], lo, hi)}
+    return {n: trace_reduce.hlo_paths(programs.hlo_texts(n))
+            for n in sorted(names)}
+
+
 def run_cell(cell: spec.Cell, seed: int, seconds: float, *, trace: bool,
              peaks: dict, events: Counter, t_start: float,
              trace_dir: Optional[Path] = None, control: bool = False,
@@ -98,6 +109,9 @@ def run_cell(cell: spec.Cell, seed: int, seconds: float, *, trace: bool,
     the calibration read (``extra``).  ``warm=False`` skips the warm-up,
     for a later run in a process that already ran every shape."""
     import jax
+    programs = harness.Programs()
+    if trace:
+        programs.start()
     ref = cell.reference()
     dm = ref.dims(cell.config)
     cfg = harness.program_config(cell.config_name, cell.config)
@@ -162,6 +176,7 @@ def run_cell(cell: spec.Cell, seed: int, seconds: float, *, trace: bool,
         if profiling["on"]:
             jax.profiler.stop_trace()
         lowered.stop()
+        programs.stop()
     counters["closed"] = client.metrics()
     compiles_window = marks["compiles_close"] - marks["compiles_open"]
     hits_window = marks["hits_close"] - marks["hits_open"]
@@ -199,13 +214,18 @@ def run_cell(cell: spec.Cell, seed: int, seconds: float, *, trace: bool,
     if trace:
         t = trace_reduce.load(str(trace_dir))
         lo, hi = trace_reduce.window_of(t)
-        reduced = trace_reduce.reduce(t, lo, hi)
+        hlo = scope_paths(programs, t, lo, hi)
+        reduced = trace_reduce.reduce(t, lo, hi, hlo=hlo)
         device["busy_s"] = reduced["busy_s"]
         device["window_s"] = reduced["window_s"]
         log("device programs (s): " + ", ".join(
             f"{k}={v:.6f}" for k, v in sorted(reduced["programs"].items(),
                                                key=lambda kv: -kv[1])[:12]))
-    record = per_layer_record(cell, dm, peaks, driver, win, counters,
+        log("device scopes (s): " + ", ".join(
+            f"{prog}:{scope}={t:.6f}"
+            for prog, v in sorted(reduced["scopes"].items())
+            for scope, t in sorted(v.items(), key=lambda kv: -kv[1])))
+    record = per_layer_record(cell, ref, dm, peaks, driver, win, counters,
                               reduced, memory) if trace else None
     recs = list(driver.recs.values())
     # free the program's state before the reference runs
@@ -240,7 +260,8 @@ def run_cell(cell: spec.Cell, seed: int, seconds: float, *, trace: bool,
     }
     if reduced is not None:
         out["breakdown"] = {"device_ops": reduced["device_ops"],
-                            "idle_gaps": reduced["idle_gaps"]}
+                            "idle_gaps": reduced["idle_gaps"],
+                            "scopes": reduced["top_scopes"]}
     out["checks"] = {"worst_gap_sigma": {
         "value": result["worst_gap_sigma"], "limit": limit}}
     out["extra"] = {"check": result, "e2e": e2e,

@@ -10,19 +10,34 @@ per-layer metric is a file of its own, found by the name that
 - per-layer metric: ``bench/metrics/<metric>.py`` with ``read(record)``;
 - plain reference: ``bench/references/<config["reference"]>.py``.
 
+A reference owns its configuration's counts as well as its forward pass:
+besides ``dims``, ``make_params`` and ``logits`` it exports
+``count_<name>(dm, pump, events)`` for each name in ``COUNTS``, the
+(ops, bytes) the roofline and utilization readers price a pump at
+(``bench/roofline.py`` holds the dense GQA counts and ``least_time``).
+A new architecture (sparse experts, window layers) is then new files
+only: a configuration, a reference with its dims, weights, logits and
+counts, a cell, and readers of its own scopes.
+
 Adding any of them is adding a file and an entry; nothing here changes.
 """
 from __future__ import annotations
 
+import bisect
 import importlib.util
 import json
 from dataclasses import dataclass
 from pathlib import Path
 from types import ModuleType
-from typing import Dict, List, Optional
+from typing import Dict, List, Optional, Sequence
 
 ROOT = Path(__file__).resolve().parent.parent
 BENCH = Path(__file__).resolve().parent
+
+#: the counts every reference exports, as ``count_<name>(dm, pump,
+#: events)``: the decode tick's (ops, bytes), a list of one (ops, bytes)
+#: per prefill dispatch, and the decode tick's attention's (ops, bytes)
+COUNTS = ("decode_tick", "prefill", "decode_attention")
 
 
 def _load_json(path: Path) -> dict:
@@ -60,8 +75,35 @@ class Cell:
         return load_module(self.root / "bench" / "metrics" / f"{name}.py")
 
     def reference(self) -> ModuleType:
-        return load_module(self.root / "bench" / "references" /
-                           f"{self.config['reference']}.py")
+        """The configuration's plain reference; raises AttributeError where
+        it lacks one of the ``COUNTS``."""
+        ref = load_module(self.root / "bench" / "references" /
+                          f"{self.config['reference']}.py")
+        missing = [f"count_{n}" for n in COUNTS
+                   if not callable(getattr(ref, f"count_{n}", None))]
+        if missing:
+            raise AttributeError(f"reference {self.config['reference']!r} "
+                                 f"lacks {', '.join(missing)}")
+        return ref
+
+
+class Counts:
+    """A configuration's counts, bound to its sizes and to the program's
+    trace events: ``counts(name, pump)`` is the reference's
+    ``count_<name>(dm, pump, events)`` with the events (dicts with a
+    ``ts``) that began inside the pump."""
+
+    def __init__(self, ref: ModuleType, dm: dict,
+                 events: Sequence[dict]) -> None:
+        self._ref, self._dm = ref, dm
+        self._events = sorted(events, key=lambda e: e["ts"])
+        self._ts = [e["ts"] for e in self._events]
+
+    def __call__(self, name: str, pump):
+        i = bisect.bisect_left(self._ts, pump.t0)
+        j = bisect.bisect_left(self._ts, pump.t1)
+        return getattr(self._ref, f"count_{name}")(self._dm, pump,
+                                                   self._events[i:j])
 
 
 def _applies(metric: dict, workload: str) -> bool:

@@ -81,16 +81,61 @@ def test_unknown_names_are_refused():
         spec.resolve("no-such-cell")
 
 
+#: a reference that is new files only: dense GQA, but with its own counts
+#: (a doubled FFN) and a reader of one of its program's scopes
+DOUBLED_FFN = """
+from pathlib import Path
+from bench import roofline, spec
+_dense = spec.load_module(Path(__file__).with_name("dense_gqa.py"))
+dims, make_params, logits = _dense.dims, _dense.make_params, _dense.logits
+
+
+def _wide(dm):
+    return dict(dm, ff=2 * dm["ff"])
+
+
+def count_decode_tick(dm, pump, events):
+    return roofline.decode_tick(_wide(dm), pump.rows, pump.context)
+
+
+def count_prefill(dm, pump, events):
+    return [roofline.prefill(_wide(dm), [seg]) for seg in pump.segments]
+
+
+def count_decode_attention(dm, pump, events):
+    return tuple(2 * x for x in
+                 roofline.decode_attention(dm, pump.rows, pump.context))
+"""
+FFN_SHARE = """
+def read(record):
+    scopes = record["device"]["scopes"].get("jit_tick", {})
+    return 100.0 * scopes.get("ffn", 0.0) / sum(scopes.values()) \\
+        if scopes else None
+"""
+
+
 def test_a_new_cell_is_new_files_only(tmp_path):
     """A configuration, mix, cell and metric added in another root, with
-    nothing under the existing bench/ edited."""
+    nothing under the existing bench/ edited; a second configuration
+    whose reference brings its own counts is priced by them in the
+    standing readers, and a new reader reads one of its scopes."""
+    from bench import roofline
+    from bench.harness import Pump
     shutil.copytree(spec.BENCH, tmp_path / "bench",
                     ignore=shutil.ignore_patterns("__pycache__", ".*"))
+    before = {p: p.read_bytes() for p in (tmp_path / "bench").rglob("*")
+              if p.is_file()}
     bench = json.loads((spec.ROOT / "BENCHMARK.json").read_text())
     b = tmp_path / "bench"
     cfg = json.loads((b / "configs" / "internlm2-1.8b.json").read_text())
     cfg["name"] = "throwaway"
     (b / "configs" / "throwaway.json").write_text(json.dumps(cfg))
+    (b / "configs" / "wide.json").write_text(
+        json.dumps(dict(cfg, name="wide", reference="doubled_ffn")))
+    (b / "references" / "doubled_ffn.py").write_text(DOUBLED_FFN)
+    (b / "metrics" / "ffn_share.py").write_text(FFN_SHARE)
+    (b / "cells" / "wide.burst.json").write_text(
+        (b / "cells" / "internlm2-1.8b.reasoning.json").read_text())
     (b / "traffic" / "burst.json").write_text(
         (b / "traffic" / "chat-shared.json").read_text())
     (b / "cells" / "throwaway.burst.json").write_text(
@@ -103,6 +148,16 @@ def test_a_new_cell_is_new_files_only(tmp_path):
     bench["workloads"].append({"name": "throwaway.burst",
                                "config": "throwaway", "traffic": "burst",
                                "chips": 1, "why": "test"})
+    bench["configs"].append({"name": "wide", "source": "x",
+                             "file": "bench/configs/wide.json",
+                             "reduced": [], "why": "test"})
+    bench["workloads"].append({"name": "wide.burst", "config": "wide",
+                               "traffic": "burst", "chips": 1,
+                               "why": "test"})
+    bench["per_layer"].append({"name": "ffn_share", "unit": "%",
+                               "better": "higher", "source": "device_trace",
+                               "layer": "kernels", "moves": "itl_p95_ms",
+                               "workloads": ["wide.burst"]})
     bench["per_layer"].append({"name": "always_one", "unit": "rows",
                                "better": "higher", "source": "host_clock",
                                "layer": "scheduler",
@@ -115,6 +170,39 @@ def test_a_new_cell_is_new_files_only(tmp_path):
     assert cell.metric_reader("always_one").read({}) == 1.0
     assert "always_one" not in {
         m["name"] for m in spec.resolve(WORKLOADS[0], tmp_path).per_layer}
+    # the wide configuration's counts price its decode ticks, the dense
+    # one's the same pumps; everything else in the record is the same
+    pumps = [Pump(0.0, 0.05, 16, 13_000, True, [], 0, 0, 0),
+             Pump(0.05, 0.3, 0, 0, False, [(1500, 0)], 1, 0, 0)]
+    device = {"programs": {"jit_tick": 0.05, "jit_pf": 0.3},
+              "scopes": {"jit_tick": {"paged_gather": 0.01, "ffn": 0.03,
+                                      "attention/gqa_grouped": 0.01}}}
+    peaks = {"flops_per_s": 197e12, "hbm_bytes_per_s": 819e9}
+    read = {}
+    for workload in ("throwaway.burst", "wide.burst"):
+        c = spec.resolve(workload, root=tmp_path)
+        ref = c.reference()
+        dm = ref.dims(c.config)
+        rec = {"dims": dm, "peaks": peaks, "pumps": pumps, "device": device,
+               "counts": spec.Counts(ref, dm, [])}
+        read[workload] = {m["name"]: c.metric_reader(m["name"]).read(rec)
+                          for m in c.per_layer
+                          if m["name"] in ("decode_mfu", "ffn_share",
+                                           "decode_step_roofline",
+                                           "decode_attention_roofline")}
+    dense, wide = read["throwaway.burst"], read["wide.burst"]
+    dm = spec.resolve("wide.burst", root=tmp_path).reference().dims(cfg)
+    wide_dm = dict(dm, ff=2 * dm["ff"])
+    assert wide["decode_mfu"] / dense["decode_mfu"] == pytest.approx(
+        roofline.decode_tick(wide_dm, 16, 13_000)[0] /
+        roofline.decode_tick(dm, 16, 13_000)[0], rel=1e-12)
+    assert wide["decode_step_roofline"] > dense["decode_step_roofline"]
+    assert wide["decode_attention_roofline"] == pytest.approx(
+        2 * dense["decode_attention_roofline"], rel=1e-12)
+    assert "ffn_share" not in dense and wide["ffn_share"] == \
+        pytest.approx(60.0)
+    # nothing that was under bench/ changed
+    assert all(p.read_bytes() == data for p, data in before.items())
 
 
 def _run(args, cwd, env):
