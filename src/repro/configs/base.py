@@ -16,6 +16,9 @@ class MoEConfig:
     num_experts: int
     top_k: int
     capacity_factor: float = 1.25
+    # rescale the top-k routing weights to sum to one (OLMoE: no; the
+    # weights are then the softmax over all experts, read at the top k)
+    norm_topk_prob: bool = True
     # d_ff of each expert is ModelConfig.d_ff (per-expert width).
 
 
@@ -50,6 +53,9 @@ class ModelConfig:
     rope: str = "rope"          # rope | mrope | none
     rope_theta: float = 1e4
     qk_norm: bool = False
+    # what one qk-norm normalises: "head" (each head's head_dim, Qwen3) or
+    # "full" (the whole projected q and k, OLMoE)
+    qk_norm_span: str = "head"
     tie_embeddings: bool = False
     moe: Optional[MoEConfig] = None
     ssm: Optional[SSMConfig] = None
@@ -64,6 +70,15 @@ class ModelConfig:
     dtype: str = "bfloat16"
     # citation provenance for the record
     source: str = ""
+
+    def __post_init__(self) -> None:
+        # a configuration read from JSON gives its expert group as a
+        # mapping
+        if isinstance(self.moe, dict):
+            object.__setattr__(self, "moe", MoEConfig(**self.moe))
+        if self.qk_norm_span not in ("head", "full"):
+            raise ValueError(f"qk_norm_span must be 'head' or 'full', got "
+                             f"{self.qk_norm_span!r}")
 
     @property
     def head_dim(self) -> int:
@@ -175,9 +190,9 @@ def reduce_for_smoke(cfg: ModelConfig) -> ModelConfig:
         dtype="float32",
     )
     if cfg.moe:
-        updates["moe"] = MoEConfig(num_experts=4,
-                                   top_k=min(cfg.moe.top_k, 2),
-                                   capacity_factor=2.0)
+        updates["moe"] = dataclasses.replace(
+            cfg.moe, num_experts=4, top_k=min(cfg.moe.top_k, 2),
+            capacity_factor=2.0)
     if cfg.ssm:
         updates["ssm"] = SSMConfig(variant=cfg.ssm.variant, state_dim=8,
                                    conv_kernel=4, expand=2, head_dim=16,

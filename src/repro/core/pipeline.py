@@ -730,7 +730,8 @@ class ServingPipeline:
         """Tick-boundary recording: scheduler gauges, the tick-duration
         histogram, backend gauge sampling (duck-typed
         ``observe_metrics`` — host ints only, never a device read), and
-        the tick's trace slice.  ``kind`` is None when the tick
+        the tick's trace slice, with any args the backend gives for it
+        (duck-typed ``tick_args``).  ``kind`` is None when the tick
         executed nothing (empty pipeline / un-triggered lazy queue)."""
         with span_of(self.obs.trace, "pipeline.observe"):
             self._g_queue.set(len(self.queue))
@@ -744,10 +745,12 @@ class ServingPipeline:
         self._tick_worked = True
         t1 = self.clock()
         self._hist_tick.observe(t1 - t0)
+        tick_args = getattr(self.backend, "tick_args", None)
+        extra = tick_args() if tick_args is not None else {}
         trace = self.obs.trace
         if trace is not None:
             trace.tick(kind, t0, t1, tick_id, batch=decode_batch,
-                       queue=len(self.queue), live=len(self.live))
+                       queue=len(self.queue), live=len(self.live), **extra)
 
     def _record_splice(self, s: Session) -> None:
         """A session just spliced into decode: its seed token exists, so

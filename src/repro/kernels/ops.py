@@ -12,6 +12,8 @@ from __future__ import annotations
 from typing import Optional
 
 import jax
+import jax.numpy as jnp
+from jax.experimental.pallas.ops.tpu.megablox import ops as megablox
 
 from repro.kernels import ref
 from repro.kernels.flash_attention import flash_attention_pallas
@@ -132,3 +134,34 @@ def flash_decode_paged(q, k_pool, v_pool, block_tables, lengths=None, *,
                                      lengths, scale=scale,
                                      num_splits=num_splits,
                                      interpret=(impl == "interpret"))
+
+
+#: the grouped matmul's row tile: the decode tick's 16 rows x 8 picks
+GROUPED_ROWS = 128
+
+
+def grouped_matmul(lhs, rhs, group_sizes, *, impl: str = "auto"
+                   ) -> jax.Array:
+    """Grouped matmul, ``ref.grouped_matmul_ref``'s semantics: lhs (m, k)
+    rows sorted by group, rhs (G, k, n), group_sizes (G,) int32 -> (m, n)
+    float32.
+
+    The kernel is JAX's megablox ``gmm`` (a Pallas kernel with a custom
+    VJP): its grid visits only the (row tile, group) pairs that hold rows,
+    so a group with no rows costs nothing and its matrix is never read,
+    and ``rhs`` is read in place (a caller may hand it every layer's
+    experts and give rows to one layer's groups only).  Tiles: up to
+    ``GROUPED_ROWS`` rows (m padded to a multiple; padding rows belong to
+    no group and are cut off), all of k, 512 columns of n."""
+    impl = _resolve(impl)
+    if impl == "xla":
+        return ref.grouped_matmul_ref(lhs, rhs, group_sizes)
+    m, k = lhs.shape
+    tm = min(GROUPED_ROWS, -(-m // 8) * 8)
+    pad = -m % tm
+    if pad:
+        lhs = jnp.pad(lhs, ((0, pad), (0, 0)))
+    out = megablox.gmm(lhs, rhs, group_sizes, jnp.float32,
+                       (tm, k, min(rhs.shape[2], 512)), None, None, False,
+                       impl == "interpret")
+    return out[:m]

@@ -139,3 +139,13 @@ def flash_attention_ref(q: jax.Array, k: jax.Array, v: jax.Array,
     w = (e / den).astype(q.dtype)
     out = jnp.einsum("bkgqs,bksd->bkgqd", w, v)
     return out.reshape(b, h, sq, dh)
+
+
+def grouped_matmul_ref(lhs: jax.Array, rhs: jax.Array,
+                       group_sizes: jax.Array) -> jax.Array:
+    """Rows of ``lhs`` (m, k), sorted by group, times their group's
+    matrix: group g holds the ``group_sizes[g]`` rows after those of the
+    groups before it, and multiplies ``rhs[g]`` (G, k, n).  Float32 out
+    (m, n); rows past the groups' sum are zero."""
+    return jax.lax.ragged_dot(lhs, rhs, group_sizes,
+                              preferred_element_type=jnp.float32)

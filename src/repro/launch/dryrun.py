@@ -285,7 +285,7 @@ def build_cell(arch: str, shape_name: str, mesh,
                                  *([None] * (len(logits_shape) - 1))))
 
             def serve_step(params, cache, tokens_t):
-                return decode_step(cfg, params, cache, tokens_t, rt=rt)
+                return decode_step(cfg, params, cache, tokens_t, rt=rt)[:2]
 
             jitted = jax.jit(serve_step,
                              in_shardings=(p_sh, cache_sh, tok_sh),

@@ -69,11 +69,21 @@ def apply_norm(cfg: ModelConfig, p: Params, x: jax.Array,
 
 def rms_norm_headwise(scale: jax.Array, x: jax.Array,
                       eps: float = 1e-6) -> jax.Array:
-    """Per-head qk-norm (Qwen3/OLMoE): normalize the trailing head_dim."""
+    """Per-head qk-norm (Qwen3): normalize the trailing head_dim."""
     xf = x.astype(jnp.float32)
     ms = jnp.mean(xf * xf, axis=-1, keepdims=True)
     return (xf * lax.rsqrt(ms + eps) * scale.astype(jnp.float32)
             ).astype(x.dtype)
+
+
+def rms_norm_full(scale: jax.Array, x: jax.Array,
+                  eps: float = 1e-6) -> jax.Array:
+    """Full-width qk-norm (OLMoE): normalize each token's whole projection,
+    every head at once, ``x`` (..., heads, head_dim) against ``scale``
+    (heads * head_dim,)."""
+    shape = x.shape
+    flat = x.reshape(shape[:-2] + (shape[-2] * shape[-1],))
+    return rms_norm_headwise(scale, flat, eps).reshape(shape)
 
 
 # ---------------------------------------------------------------------------
@@ -178,8 +188,9 @@ def init_attention(cfg: ModelConfig, key, dtype) -> Params:
         "wo": dense_init(ks[3], (h, dh, d), h * dh, dtype),
     }
     if cfg.qk_norm:
-        p["q_norm"] = jnp.ones((dh,), dtype)
-        p["k_norm"] = jnp.ones((dh,), dtype)
+        full = cfg.qk_norm_span == "full"
+        p["q_norm"] = jnp.ones((h * dh if full else dh,), dtype)
+        p["k_norm"] = jnp.ones((kv * dh if full else dh,), dtype)
     return p
 
 
@@ -191,8 +202,10 @@ def qkv_project(cfg: ModelConfig, p: Params, x: jax.Array,
     k = jnp.einsum("bsd,dhk->bshk", x, p["wk"])
     v = jnp.einsum("bsd,dhk->bshk", x, p["wv"])
     if cfg.qk_norm:
-        q = rms_norm_headwise(p["q_norm"], q)
-        k = rms_norm_headwise(p["k_norm"], k)
+        norm = (rms_norm_full if cfg.qk_norm_span == "full"
+                else rms_norm_headwise)
+        q = norm(p["q_norm"], q)
+        k = norm(p["k_norm"], k)
     q = _rope_dispatch(cfg, q, positions)
     k = _rope_dispatch(cfg, k, positions)
     q = constrain(q, "batch", None, "heads", None)

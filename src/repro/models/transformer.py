@@ -180,12 +180,37 @@ def _block_attn_full(cfg, rt, blk, h, positions, collect_cache,
     hn2 = L.apply_norm(cfg, blk["norm2"], h)
     aux = jnp.zeros((), jnp.float32)
     if cfg.family == "moe":
-        out, aux = M.apply_moe(cfg, blk["moe"], hn2)
+        out, aux, _ = M.apply_moe(cfg, blk["moe"], hn2)
     else:
         out = L.apply_ffn(cfg, blk["ffn"], hn2)
     h = _residual_constrain(rt, h + out)
     cache = (k, v) if collect_cache else None
     return h, aux, cache
+
+
+def _ffn_or_moe(cfg: ModelConfig, blk: Params, hn2: jax.Array,
+                experts: Optional[Params] = None,
+                layer: Optional[jax.Array] = None):
+    """A block's feed-forward half: ``(out, rows)``, where ``rows`` (E,)
+    counts the token rows each expert computed (None without experts);
+    ``experts``/``layer`` as ``_layer_xs`` splits them."""
+    if cfg.family == "moe":
+        out, _, rows = M.apply_moe(cfg, blk["moe"], hn2, experts, layer)
+        return out, rows
+    return L.apply_ffn(cfg, blk["ffn"], hn2), None
+
+
+def _layer_xs(cfg: ModelConfig, layers: Params):
+    """``(xs, experts)``: what a serving scan over layers slices, and an
+    MoE model's expert matrices, kept whole (stacked over layers) for
+    ``apply_moe`` to read a layer's experts in place; the scan then also
+    carries each layer's index as its last xs. Without experts
+    ``(layers, None)``, and the scan is as before."""
+    if cfg.family != "moe":
+        return layers, None
+    moe = dict(layers["moe"])
+    experts = {n: moe.pop(n) for n in M.EXPERT_KEYS if n in moe}
+    return (dict(layers, moe=moe), jnp.arange(cfg.num_layers)), experts
 
 
 def _maybe_remat(fn, rt: ModelRuntime):
@@ -535,11 +560,13 @@ def prefill_packed(cfg: ModelConfig, params: Params, tokens: jax.Array,
     (`attention_packed`), so each segment computes exactly what its own
     sequential prefill would have.
 
-    Returns ``(logits, {"k", "v"})``: ``logits`` (N, V) gathered at
-    ``last_idx`` (N,) — each segment's last fresh token, padded entries
-    point anywhere harmless — and suffix-only cache parts
+    Returns ``(logits, {"k", "v"}, expert_rows)``: ``logits`` (N, V)
+    gathered at ``last_idx`` (N,) — each segment's last fresh token,
+    padded entries point anywhere harmless — suffix-only cache parts
     (L, P, KV, dh) for the caller to scatter into per-segment paged
-    blocks.  Attention families only, like ``prefill_suffix``.
+    blocks, and the token rows each expert of each layer computed
+    (L, E) int32, None without experts.  Attention families only, like
+    ``prefill_suffix``.
     """
     if cfg.family in ("ssm", "hybrid"):
         raise ValueError("packed prefill is attention-only; SSM state "
@@ -557,9 +584,12 @@ def prefill_packed(cfg: ModelConfig, params: Params, tokens: jax.Array,
     else:
         k_seg, k_pos = seg_ids, positions
 
+    layers, experts = _layer_xs(cfg, params["layers"])
+
     def block(carry, xs):
         h = carry
         blk, pk, pv = xs
+        blk, layer = blk if experts is not None else (blk, None)
         hn = L.apply_norm(cfg, blk["norm1"], h)
         q, k, v = L.qkv_project(cfg, blk["attn"], hn, pos_in)
         if use_prefix:
@@ -570,21 +600,18 @@ def prefill_packed(cfg: ModelConfig, params: Params, tokens: jax.Array,
         attn = L.attention_packed(cfg, q, k_all, v_all, q_seg=seg_ids,
                                   k_seg=k_seg, q_pos=positions, k_pos=k_pos)
         h = h + L.attention_output(blk["attn"], attn)
-        hn2 = L.apply_norm(cfg, blk["norm2"], h)
-        if cfg.family == "moe":
-            out, _ = M.apply_moe(cfg, blk["moe"], hn2)
-        else:
-            out = L.apply_ffn(cfg, blk["ffn"], hn2)
+        out, rows = _ffn_or_moe(cfg, blk, L.apply_norm(cfg, blk["norm2"],
+                                                      h), experts, layer)
         h = _residual_constrain(rt, h + out)
-        return h, (k[0], v[0])
+        return h, (k[0], v[0], rows)
 
-    h, (k_suf, v_suf) = lax.scan(
-        block, h, (params["layers"], prefix_k, prefix_v))
+    h, (k_suf, v_suf, rows) = lax.scan(
+        block, h, (layers, prefix_k, prefix_v))
     h = L.apply_norm(cfg, params["final_norm"], h)
     h_last = h[:, last_idx.astype(jnp.int32)]                 # (1, N, d)
     logits = L.lm_logits(cfg, params["embed"], h_last)
     return logits[0], {"k": k_suf.astype(cache_dtype),
-                       "v": v_suf.astype(cache_dtype)}
+                       "v": v_suf.astype(cache_dtype)}, rows
 
 
 # ---------------------------------------------------------------------------
@@ -597,7 +624,9 @@ def decode_step(cfg: ModelConfig, params: Params, cache: Dict[str, Any],
     """One decode step.
 
     tokens_t: (B,) or (B,K) for audio. Uses cache['len'] as the write
-    position (per-batch uniform). Returns (logits (B,V)|(B,K,V), cache).
+    position (per-batch uniform). Returns (logits (B,V)|(B,K,V), cache,
+    expert_rows): the token rows each expert of each layer computed,
+    (L, E) int32, None for models without experts.
     """
     bsz = tokens_t.shape[0]
     toks = tokens_t[:, None] if tokens_t.ndim == 1 else tokens_t[..., None]
@@ -607,19 +636,21 @@ def decode_step(cfg: ModelConfig, params: Params, cache: Dict[str, Any],
     if cfg.rope == "mrope":
         positions = jnp.broadcast_to(positions[None], (3, bsz, 1))
 
+    rows = None
     if cfg.family in ("ssm", "hybrid"):
         new_cache, h = _decode_ssm(cfg, params, cache, h, positions, rt)
     elif "block_tables" in cache:
-        new_cache, h = _decode_attn_paged(cfg, params, cache, h,
-                                          positions, rt)
+        new_cache, h, rows = _decode_attn_paged(cfg, params, cache, h,
+                                                positions, rt)
     else:
-        new_cache, h = _decode_attn(cfg, params, cache, h, positions, rt)
+        new_cache, h, rows = _decode_attn(cfg, params, cache, h,
+                                          positions, rt)
     h = L.apply_norm(cfg, params["final_norm"], h)
     logits = L.lm_logits(cfg, params["embed"], h)
     new_cache["len"] = cache["len"] + 1
     if cfg.num_codebooks:
-        return logits[:, :, 0], new_cache
-    return logits[:, 0], new_cache
+        return logits[:, :, 0], new_cache, rows
+    return logits[:, 0], new_cache, rows
 
 
 @jax.named_scope("kv_write")
@@ -644,18 +675,15 @@ def _decode_attn(cfg, params, cache, h, positions, rt):
         k_c, v_c = _write_kv(k_c, v_c, k, v, cache["len"])
         attn = L.attention_decode(cfg, q, k_c, v_c, cache["len"] + 1)
         h = h + L.attention_output(blk["attn"], attn)
-        hn2 = L.apply_norm(cfg, blk["norm2"], h)
-        if cfg.family == "moe":
-            out, _ = M.apply_moe(cfg, blk["moe"], hn2)
-        else:
-            out = L.apply_ffn(cfg, blk["ffn"], hn2)
-        return h + out, (k_c, v_c)
+        out, rows = _ffn_or_moe(cfg, blk, L.apply_norm(cfg, blk["norm2"],
+                                                      h))
+        return h + out, (k_c, v_c, rows)
 
-    h, (k_new, v_new) = lax.scan(
+    h, (k_new, v_new, rows) = lax.scan(
         block, h, (params["layers"], cache["k"], cache["v"]))
     new_cache = dict(cache)
     new_cache["k"], new_cache["v"] = k_new, v_new
-    return new_cache, h
+    return new_cache, h, rows
 
 
 @jax.named_scope("kv_write")
@@ -694,10 +722,12 @@ def _paged_gather(pool, tables):
 
 def _decode_attn_paged(cfg, params, cache, h, positions, rt):
     tables = cache["block_tables"]
+    layers, experts = _layer_xs(cfg, params["layers"])
 
     def block(carry, xs):
         h = carry
         blk, k_p, v_p = xs
+        blk, layer = blk if experts is not None else (blk, None)
         hn = L.apply_norm(cfg, blk["norm1"], h)
         q, k, v = L.qkv_project(cfg, blk["attn"], hn, positions)
         k_p, v_p = _paged_write_kv(k_p, v_p, k, v, tables, cache["len"])
@@ -705,46 +735,42 @@ def _decode_attn_paged(cfg, params, cache, h, positions, rt):
         v_seq = _paged_gather(v_p, tables)
         attn = L.attention_decode(cfg, q, k_seq, v_seq, cache["len"] + 1)
         h = h + L.attention_output(blk["attn"], attn)
-        hn2 = L.apply_norm(cfg, blk["norm2"], h)
-        if cfg.family == "moe":
-            out, _ = M.apply_moe(cfg, blk["moe"], hn2)
-        else:
-            out = L.apply_ffn(cfg, blk["ffn"], hn2)
-        return h + out, (k_p, v_p)
+        out, rows = _ffn_or_moe(cfg, blk, L.apply_norm(cfg, blk["norm2"],
+                                                      h), experts, layer)
+        return h + out, (k_p, v_p, rows)
 
-    h, (k_new, v_new) = lax.scan(
-        block, h, (params["layers"], cache["k"], cache["v"]))
+    h, (k_new, v_new, rows) = lax.scan(
+        block, h, (layers, cache["k"], cache["v"]))
     new_cache = dict(cache)
     new_cache["k"], new_cache["v"] = k_new, v_new
-    return new_cache, h
+    return new_cache, h, rows
 
 
 def _layer_block(cfg, rt, blk, cache, h, positions, k_c, v_c):
-    """One unrolled decode layer; returns (h, updated k_c, v_c)."""
+    """One unrolled decode layer; returns (h, updated k_c, v_c, expert
+    rows or None)."""
     hn = L.apply_norm(cfg, blk["norm1"], h)
     q, k, v = L.qkv_project(cfg, blk["attn"], hn, positions)
     k_c, v_c = _write_kv(k_c, v_c, k, v, cache["len"])
     attn = L.attention_decode(cfg, q, k_c, v_c, cache["len"] + 1)
     h = h + L.attention_output(blk["attn"], attn)
-    hn2 = L.apply_norm(cfg, blk["norm2"], h)
-    if cfg.family == "moe":
-        out, _ = M.apply_moe(cfg, blk["moe"], hn2)
-    else:
-        out = L.apply_ffn(cfg, blk["ffn"], hn2)
-    return h + out, k_c, v_c
+    out, rows = _ffn_or_moe(cfg, blk, L.apply_norm(cfg, blk["norm2"], h))
+    return h + out, k_c, v_c, rows
 
 
 def _decode_attn_unrolled(cfg, params, cache, h, positions, rt):
     k_full, v_full = cache["k"], cache["v"]
+    rows = []
     for i in range(cfg.num_layers):
         blk = jax.tree.map(lambda x: x[i], params["layers"])
-        h, k_c, v_c = _layer_block(cfg, rt, blk, cache, h, positions,
-                                   k_full[i], v_full[i])
+        h, k_c, v_c, r = _layer_block(cfg, rt, blk, cache, h, positions,
+                                      k_full[i], v_full[i])
+        rows.append(r)
         k_full = lax.dynamic_update_index_in_dim(k_full, k_c, i, 0)
         v_full = lax.dynamic_update_index_in_dim(v_full, v_c, i, 0)
     new_cache = dict(cache)
     new_cache["k"], new_cache["v"] = k_full, v_full
-    return new_cache, h
+    return new_cache, h, (jnp.stack(rows) if cfg.family == "moe" else None)
 
 
 def _decode_ssm(cfg, params, cache, h, positions, rt):

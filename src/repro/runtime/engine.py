@@ -39,6 +39,7 @@ from repro.models import (ModelRuntime, DEFAULT_RUNTIME, decode_step,
                           forward_hidden, make_cache, make_paged_cache,
                           prefill, prefill_packed, prefill_suffix)
 from repro.models.layers import lm_logits
+from repro.models.moe import routing_stats
 from repro.obs import span_of
 from repro.runtime import lowering, sanitizer
 from repro.runtime.bucketing import BucketLadder
@@ -82,6 +83,11 @@ class GenState:
     # host-side: does any live row sample?  Greedy-only batches compile
     # and run the exact pre-sampling tick (bit-identical streams).
     sampling: bool = False
+    # MoE only: (2,) int32 routing stats of the last dispatch not yet read
+    # back (`repro.models.moe.routing_stats`: (layer, expert) pairs
+    # touched, largest group); `ContinuousEngine._sync` reads them with
+    # the stop flags
+    routing: Optional[jax.Array] = None
 
     @property
     def capacity(self) -> int:
@@ -163,7 +169,7 @@ class InferenceEngine:
 
             @partial(jax.jit, donate_argnums=(1,))
             def step(params, cache, tokens_t):
-                return decode_step(cfg, params, cache, tokens_t, rt=rt)
+                return decode_step(cfg, params, cache, tokens_t, rt=rt)[:2]
 
             self._decode_cache[key] = step
             self.compile_count += 1
@@ -178,7 +184,9 @@ class InferenceEngine:
         pure-greedy tick (argmax only — the pre-sampling fast path);
         ``sampling=True`` adds per-row categorical sampling, with greedy
         (temperature 0) rows still taking the identical argmax value.
-        Codebook models (tok_ndim 2) are always greedy."""
+        Codebook models (tok_ndim 2) are always greedy.  MoE models'
+        ticks also return the tick's routing stats
+        (`repro.models.moe.routing_stats`) as a sixth output."""
         key = ("tick", tok_ndim, sampling)
         if key not in self._decode_cache:
             cfg, rt = self.cfg, self.rt
@@ -188,8 +196,8 @@ class InferenceEngine:
             def tick(params, cache, cur, emitted, counts, done, budget,
                      eos, temp, top_k, top_p, seed):
                 prev_len = cache["len"]
-                logits, cache2 = decode_step(cfg, params, cache, cur,
-                                             rt=rt)
+                logits, cache2, rows = decode_step(cfg, params, cache,
+                                                   cur, rt=rt)
                 with jax.named_scope("sample"):
                     if sampling and tok_ndim == 1:
                         nxt = sample_tokens(logits, temperature=temp,
@@ -211,7 +219,10 @@ class InferenceEngine:
                     jnp.any(tok[:, None] == eos, axis=-1)
                 mask = done if cur.ndim == 1 else done[:, None]
                 cur2 = jnp.where(mask, cur, nxt)
-                return cache2, cur2, emitted2, counts2, done2
+                out = (cache2, cur2, emitted2, counts2, done2)
+                if rows is not None:
+                    out += (routing_stats(rows),)
+                return out
 
             self._decode_cache[key] = tick
             self.compile_count += 1
@@ -476,10 +487,12 @@ class InferenceEngine:
             @jax.jit
             def pf(params, tokens, seg_ids, positions, last_idx,
                    prefix_k, prefix_v, prefix_seg, prefix_pos):
-                return prefill_packed(
+                logits, parts, rows = prefill_packed(
                     cfg, params, tokens, seg_ids, positions, last_idx,
                     prefix_k, prefix_v, prefix_seg, prefix_pos, rt=rt,
                     cache_dtype=dt)
+                return logits, parts, (None if rows is None
+                                       else routing_stats(rows))
 
             self._prefill_cache[key] = pf
             self.compile_count += 1
@@ -498,10 +511,12 @@ class InferenceEngine:
         ``prefix_seg``/``prefix_pos``).  Everything is padded here to
         (pack, prefix, segment) buckets so callers never mint new cells.
 
-        Returns ``(logits, parts)``: per-segment last-token logits
-        (seg_b, V) — rows past ``len(suffixes)`` are padding — and flat
-        suffix KV (L, pack_b, KV, dh) laid out exactly as the
-        concatenated suffixes, for per-segment scatter into paged blocks.
+        Returns ``(logits, parts, routing)``: per-segment last-token
+        logits (seg_b, V) — rows past ``len(suffixes)`` are padding —
+        flat suffix KV (L, pack_b, KV, dh) laid out exactly as the
+        concatenated suffixes, for per-segment scatter into paged blocks,
+        and the dispatch's routing stats (`GenState.routing`; None
+        without experts).
         """
         n = len(suffixes)
         lens = [len(s) for s in suffixes]
@@ -542,12 +557,14 @@ class InferenceEngine:
         pure-argmax tick; states with sampled rows run the per-row
         categorical variant (greedy rows still take the argmax value)."""
         tick = self._tick_fn(state.cur.ndim, state.sampling)
-        cache, cur, emitted, counts, done = tick(
+        out = tick(
             self.params, state.cache, state.cur, state.emitted,
             state.counts, state.done, state.budget, state.eos,
             state.temp, state.top_k, state.top_p, state.seed)
+        cache, cur, emitted, counts, done = out[:5]
         return replace(state, cache=cache, cur=cur, emitted=emitted,
-                       counts=counts, done=done)
+                       counts=counts, done=done,
+                       routing=out[5] if len(out) > 5 else None)
 
     def read_out(self, state: GenState,
                  token_lists: Sequence[Sequence[int]]) -> List[List[int]]:
@@ -768,6 +785,14 @@ class ContinuousEngine(PipelineBackend):
         self._chunk_slots: Dict[int, int] = {}
         self._since_sync = 0
         self.decode_ticks = 0
+        # MoE routing reads (`_sync`): the latest (experts touched,
+        # largest group), their running total of touched pairs, the part
+        # of it `observe_metrics` has counted, and this tick's reads for
+        # its trace event (`tick_args`)
+        self.routing_last: Optional[Tuple[int, int]] = None
+        self.routed_pairs = 0
+        self._routed_counted = 0
+        self._tick_routing: Optional[Tuple[int, int]] = None
         # throwaway-session id namespace for warmup_aot (far below the
         # generate() negative ids; decremented per warm session)
         self._warm_id = -(10 ** 9)
@@ -814,6 +839,24 @@ class ContinuousEngine(PipelineBackend):
         if self.prefix_cache is not None:
             for k, v in self.prefix_cache.metrics().items():
                 m.gauge("prefix." + k).set(v)
+        if self.routing_last is not None:
+            m.gauge("moe.experts_touched").set(self.routing_last[0])
+            m.gauge("moe.max_expert_rows").set(self.routing_last[1])
+            m.counter("moe.routed_pairs").inc(
+                self.routed_pairs - self._routed_counted)
+            self._routed_counted = self.routed_pairs
+
+    def tick_args(self) -> Dict[str, int]:
+        """Args for the trace event of the tick that just ran (the
+        duck-typed hook `ServingPipeline._tick_boundary` calls): the
+        routing reads it made — (layer, expert) pairs touched, summed
+        over its dispatches, and the largest group — or nothing where it
+        made none (dense models)."""
+        if self._tick_routing is None:
+            return {}
+        touched, largest = self._tick_routing
+        self._tick_routing = None
+        return {"experts_touched": touched, "max_expert_rows": largest}
 
     def free_kv_tokens(self) -> Optional[int]:
         """Token capacity of blocks neither held nor reserved — the
@@ -1211,7 +1254,8 @@ class ContinuousEngine(PipelineBackend):
         compiles0 = eng.compile_count
         top = self.max_len if self.max_len is not None \
             else ladder.seq_buckets[-1]
-        saved = (self.prefill_tokens, self.decode_ticks, self.cow_blocks)
+        saved = (self.prefill_tokens, self.decode_ticks, self.cow_blocks,
+                 self.routed_pairs)
         prefix_was, pc = self._prefix_enabled, self.prefix_cache
         self._prefix_enabled, self.prefix_cache = False, None
         rounds = 0
@@ -1297,7 +1341,11 @@ class ContinuousEngine(PipelineBackend):
             # the pure-argmax tick back
             if self.state is not None:
                 self.state = replace(self.state, sampling=False)
-            self.prefill_tokens, self.decode_ticks, self.cow_blocks = saved
+            (self.prefill_tokens, self.decode_ticks, self.cow_blocks,
+             self.routed_pairs) = saved
+            self._routed_counted = min(self._routed_counted,
+                                       self.routed_pairs)
+            self._tick_routing = None
             self._prefix_enabled = prefix_was
             if prefix_was:
                 self.prefix_cache = pc if pc is not None else \
@@ -1518,7 +1566,7 @@ class ContinuousEngine(PipelineBackend):
         try:
             # ---- THE dispatch -----------------------------------------
             with span_of(self.trace, "engine.dispatch"):
-                logits, parts = eng.prefill_packed_flat(
+                logits, parts, routing = eng.prefill_packed_flat(
                     suffixes, offsets, prefix_k, prefix_v, prefix_seg,
                     prefix_pos)
             with span_of(self.trace, "engine.splice"):
@@ -1631,9 +1679,10 @@ class ContinuousEngine(PipelineBackend):
                     for key in _BATCH_AXIS0:
                         cache[key] = cache[key].at[idx].set(
                             _rows(rows.cache[key], key, ns))
-                    self.state = self._spliced(cache, rows, idx, ns)
+                    self.state = replace(self._spliced(cache, rows, idx, ns),
+                                         routing=routing)
                 else:
-                    self.state = replace(st, cache=cache)
+                    self.state = replace(st, cache=cache, routing=routing)
         except Exception:
             # mirror prefill_batch's sweep: free admission tables and
             # holds, neutralize any slot whose row state may have been
@@ -2233,7 +2282,13 @@ class ContinuousEngine(PipelineBackend):
         self._since_sync = 0
         st = self.state
         with span_of(self.trace, "engine.wait"):
-            done = np.asarray(st.done)    # turbolint: allow-sync(stop-flag flush)
+            if st.routing is None:
+                done = np.asarray(st.done)    # turbolint: allow-sync(stop-flag flush)
+            else:
+                # turbolint: allow-sync(stop-flag flush; MoE routing stats ride the same read)
+                done, routing = jax.device_get((st.done, st.routing))
+                st = self.state = replace(st, routing=None)
+                self._read_routing(*routing.tolist())
         if not any(done[slot] for slot, s in enumerate(self.sessions)
                    if s is not None):
             return
@@ -2269,6 +2324,13 @@ class ContinuousEngine(PipelineBackend):
                     cache["block_tables"] = cache["block_tables"].at[
                         jnp.asarray(np.array(freed_slots, np.int32))].set(0)
                     self.state = replace(st, cache=cache)
+
+    def _read_routing(self, touched: int, largest: int) -> None:
+        """Host bookkeeping of one routing read (`_sync`)."""
+        self.routing_last = (touched, largest)
+        self.routed_pairs += touched
+        t, m = self._tick_routing or (0, 0)
+        self._tick_routing = (t + touched, max(m, largest))
 
     @property
     def live_tokens(self) -> int:

@@ -60,7 +60,7 @@ def test_prefill_decode_matches_forward(arch):
         else (2, cfg.vocab_size)
     assert logits_p.shape == expect
     nxt = jnp.argmax(logits_p, -1)
-    logits_d, cache = decode_step(cfg, params, cache, nxt)
+    logits_d, cache, _ = decode_step(cfg, params, cache, nxt)
     assert np.isfinite(np.asarray(logits_d)).all()
     # oracle: full forward over the extended sequence
     if cfg.num_codebooks:

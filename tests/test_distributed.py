@@ -93,7 +93,7 @@ def test_decode_sharded_matches_single_device():
         logits, cache = prefill(cfg, params, pr["tokens"], max_len=32,
                                 cache_dtype=jnp.float32)
         nxt = jnp.argmax(logits, -1)
-        ref, _ = decode_step(cfg, params, dict(cache), nxt)
+        ref, _, _ = decode_step(cfg, params, dict(cache), nxt)
 
         mesh = jax.make_mesh((2, 4), ("data", "model"),
                              axis_types=(AUTO, AUTO))
@@ -110,7 +110,7 @@ def test_decode_sharded_matches_single_device():
             params_p = jax.device_put(params, p_sh)
             cache_p = jax.device_put(cache, c_sh)
             nxt_p = jax.device_put(nxt, t_sh)
-            got, _ = jax.jit(
+            got, _, _ = jax.jit(
                 lambda p, c, t: decode_step(cfg, p, c, t),
                 in_shardings=(p_sh, c_sh, t_sh))(params_p, cache_p, nxt_p)
         np.testing.assert_allclose(np.asarray(got), np.asarray(ref),

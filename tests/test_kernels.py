@@ -374,3 +374,28 @@ def test_flash_matches_model_chunked_attention():
         causal=True, impl="interpret", block_q=16, block_k=16)
     np.testing.assert_allclose(np.asarray(jnp.moveaxis(got, 1, 2)),
                                np.asarray(want), rtol=3e-4, atol=3e-4)
+
+
+# ---------------------------------------------------------------------------
+# grouped matmul (the dropless expert layer's)
+# ---------------------------------------------------------------------------
+
+@pytest.mark.parametrize("sizes,groups", [
+    ((5, 0, 12, 7), 4),             # an empty group; rows to every other
+    ((3, 0, 9, 4), 12),             # the last 8 groups hold no rows
+    ((0, 40, 0, 0, 0, 0, 0, 0), 8),  # one group, rows past one row tile
+])
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
+def test_grouped_matmul_kernel(sizes, groups, dtype):
+    """Interpret-mode megablox gmm against ragged_dot; ``groups`` beyond
+    ``sizes`` hold no rows (a stack of layers read in place)."""
+    k, n = 256, 192
+    m = sum(sizes)
+    gs = jnp.asarray(list(sizes) + [0] * (groups - len(sizes)), jnp.int32)
+    lhs = jax.random.normal(jax.random.key(m), (m, k)).astype(dtype)
+    rhs = (jax.random.normal(jax.random.key(groups), (groups, k, n)) /
+           16).astype(dtype)
+    got = ops.grouped_matmul(lhs, rhs, gs, impl="interpret")
+    want = ref.grouped_matmul_ref(lhs, rhs, gs)
+    assert got.shape == (m, n) and got.dtype == jnp.float32
+    _assert_close(got, want, dtype)

@@ -63,10 +63,10 @@ def test_packed_flat_matches_single_segment(engine):
     kv = getattr(cfg, "num_kv_heads", cfg.num_heads) or cfg.num_heads
     zero = jnp.zeros((cfg.num_layers, 0, kv, dh), jnp.float32)
     zseg = jnp.asarray(np.zeros((0,), np.int32))
-    la, _ = engine.prefill_packed_flat([a], [0], zero, zero, zseg, zseg)
-    lab, _ = engine.prefill_packed_flat([a, b], [0, 0], zero, zero,
-                                        zseg, zseg)
-    lb, _ = engine.prefill_packed_flat([b], [0], zero, zero, zseg, zseg)
+    la, _, _ = engine.prefill_packed_flat([a], [0], zero, zero, zseg, zseg)
+    lab, _, _ = engine.prefill_packed_flat([a, b], [0, 0], zero, zero,
+                                           zseg, zseg)
+    lb, _, _ = engine.prefill_packed_flat([b], [0], zero, zero, zseg, zseg)
     tol = dict(rtol=1e-5, atol=1e-5)
     np.testing.assert_allclose(np.asarray(la[0]), np.asarray(lab[0]), **tol)
     np.testing.assert_allclose(np.asarray(lb[0]), np.asarray(lab[1]), **tol)

@@ -18,6 +18,7 @@ import jax.numpy as jnp
 import pytest
 from jax.sharding import SingleDeviceSharding
 
+from repro.kernels import ops
 from repro.kernels.flash_attention import flash_attention_pallas
 from repro.kernels.flash_decode import (flash_decode_paged_pallas,
                                         flash_decode_pallas)
@@ -97,3 +98,16 @@ def test_norm_compiles(one_chip, rms):
     shapes = [((1024, D_MODEL), BF16), vec] + ([] if rms else [vec])
     text = compiled_text(fn, one_chip, *shapes)
     assert "tpu_custom_call" in text
+
+
+@pytest.mark.parametrize("m", [128, 4096])         # a decode tick, a pack
+@pytest.mark.parametrize("k,n", [(2048, 1024), (1024, 2048)])
+def test_grouped_matmul_compiles(one_chip, m, k, n):
+    """OLMoE's experts (d_model 2048, width 1024), 8 layers of 64 read in
+    place: the kernel, with no copy of the stack beside it."""
+    fn = functools.partial(ops.grouped_matmul, impl="pallas")
+    args = [jax.ShapeDtypeStruct(s, d, sharding=one_chip) for s, d in
+            (((m, k), BF16), ((8 * 64, k, n), BF16), ((8 * 64,), I32))]
+    compiled = jax.jit(fn).lower(*args).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+    assert compiled.memory_analysis().temp_size_in_bytes < 64 * k * n * 2
